@@ -4,20 +4,32 @@
                interpreted proxy: no cross-query view sharing)
   single_root  one batch, shared views, all queries at one root
   multi_root   + find-roots (the paper's 2-5x layer)
-  parallel     + domain parallelism over 4 host devices (subprocess)
+  parallel     + domain parallelism over 4 devices (``common.on_devices``:
+               the accelerator's own devices, or 4 forced host devices on
+               the CPU host)
 """
 
 from __future__ import annotations
 
 import os
-import subprocess
 import sys
-import textwrap
 
-from benchmarks.common import BENCH_SCALE, row, timeit
-from repro.api import connect
+from benchmarks.common import (BENCH_SCALE, devices_main, on_devices, row,
+                               timeit)
+from repro.api import ExecutionConfig, connect
 from repro.data import datasets as D
 from repro.ml.covar import covar_queries
+
+
+def parallel_main(ndev: int, name: str) -> dict:
+    """The multi-root batch over an ``ndev``-device mesh: median seconds."""
+    import jax
+
+    ds = D.make(name, scale=BENCH_SCALE)
+    qs, _ = covar_queries(ds)
+    mesh = jax.make_mesh((ndev,), ("data",), devices=jax.devices()[:ndev])
+    v = connect(ds, config=ExecutionConfig(mesh=mesh)).views(qs)
+    return {"seconds": timeit(lambda: v.run())}
 
 
 def main():
@@ -42,38 +54,14 @@ def main():
     lines.append(row(f"f5/{name}/multi_root", t_mr,
                      f"V={b_mr.stats.n_views};speedup={t_sr / t_mr:.2f}x"))
 
-    # parallel: shard_map over 4 forced host devices (own process)
-    code = f"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-import time, jax
-import repro
-from repro.data import datasets as D
-from repro.ml.covar import covar_queries
-ds = D.make({name!r}, scale={BENCH_SCALE})
-qs, _ = covar_queries(ds)
-mesh = jax.make_mesh((4,), ("data",))
-db = repro.connect(ds, config=repro.ExecutionConfig(mesh=mesh))
-v = db.views(qs)
-jax.block_until_ready(v.run())   # warmup/compile once (runner is cached)
-t0 = time.perf_counter()
-for _ in range(3):
-    jax.block_until_ready(v.run())
-print((time.perf_counter() - t0) / 3)
-"""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    try:
-        out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                             env=env, capture_output=True, text=True, timeout=600)
-        t_par = float(out.stdout.strip().splitlines()[-1])
-        lines.append(row(f"f5/{name}/parallel4", t_par,
-                         f"speedup={t_mr / t_par:.2f}x"))
-    except Exception as e:  # pragma: no cover
-        lines.append(row(f"f5/{name}/parallel4", 0.0, f"failed:{e}"))
+    # parallel: the same batch domain-parallel over a 4-device mesh
+    t_par = on_devices("benchmarks.bench_fig5_ablation", 4, parallel_main,
+                       name)["seconds"]
+    lines.append(row(f"f5/{name}/parallel4", t_par,
+                     f"speedup={t_mr / t_par:.2f}x"))
     return lines
 
 
 if __name__ == "__main__":
-    print("\n".join(main()))
+    if not devices_main(sys.argv, parallel_main):
+        print("\n".join(main()))

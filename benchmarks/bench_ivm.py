@@ -15,26 +15,23 @@ which ``benchmarks/run.py`` serializes to ``BENCH_ivm.json`` so CI records
 the perf trajectory.
 
 Sharded rows (DESIGN.md §6): the same ridge workload over 2- and 4-device
-host meshes — steady-state tick under ``jax.transfer_guard("disallow")``
-plus sharded serving read latency.  Device count is fixed at jax import
-time, so each mesh size runs in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N``; the contract
-fields (retraces, allclose vs a local recompute) ride along so the perf
-gate can hold them hard while wall times gate loose.
+meshes — steady-state tick under ``jax.transfer_guard("disallow")`` plus
+sharded serving read latency.  On an accelerator they run in this process
+on its devices; on the CPU host each mesh size runs in a child with that
+many forced host devices (``common.on_devices``).  The contract fields
+(retraces, allclose vs a local recompute) ride along so the perf gate can
+hold them hard while wall times gate loose.
 
     PYTHONPATH=src python -m benchmarks.bench_ivm
 """
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
 
 import numpy as np
 
-from benchmarks.common import BENCH_SCALE, row, timeit
+from benchmarks.common import BENCH_SCALE, devices_main, on_devices, row, timeit
 from repro.data import datasets as D
 from repro.data import relations as relmod
 from repro.data.relations import DeltaBatchUpdate
@@ -60,17 +57,15 @@ def _fact_update(ds, rng, frac: float) -> DeltaBatchUpdate:
 
 
 def sharded_main(ndev: int) -> dict:
-    """Sharded-IVM measurement body.  Runs in a subprocess whose XLA host
-    platform was forced to ``ndev`` devices (``_run_sharded``); measures the
-    steady-state sharded tick under ``transfer_guard("disallow")`` — the
-    zero-host-transfer contract — and the sharded serving read latency."""
+    """Sharded-IVM measurement body over ``ndev`` devices
+    (``common.on_devices`` places it); measures the steady-state sharded
+    tick under ``transfer_guard("disallow")`` — the zero-host-transfer
+    contract — and the sharded serving read latency."""
     import jax
 
     from repro.api import ExecutionConfig
 
-    if len(jax.devices()) < ndev:
-        raise RuntimeError(f"need {ndev} devices, have {len(jax.devices())}")
-    mesh = jax.make_mesh((ndev,), ("data",))
+    mesh = jax.make_mesh((ndev,), ("data",), devices=jax.devices()[:ndev])
     ds = D.make("favorita", scale=BENCH_SCALE)
     rng = np.random.default_rng(11)
     # shard the fact explicitly: at small BENCH_SCALE the dense
@@ -108,20 +103,6 @@ def sharded_main(ndev: int) -> dict:
         "rows_per_shard": int(topo["rows_per_shard"]),
         "psums_per_tick_fact": int(topo["psums_per_tick"][ds.fact]),
     }
-
-
-def _run_sharded(ndev: int) -> dict:
-    """Spawn ``sharded_main(ndev)`` with a forced ``ndev``-device host mesh
-    (device count is fixed at jax import time, hence the subprocess)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={ndev}").strip()
-    env["JAX_PLATFORMS"] = "cpu"             # host mesh: portable everywhere
-    env.setdefault("PYTHONPATH", "src")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_ivm", "--sharded", str(ndev)],
-        check=True, env=env, capture_output=True, text=True)
-    return json.loads(out.stdout.splitlines()[-1])
 
 
 def main():
@@ -182,10 +163,10 @@ def main():
         "ivm/cube_delta_1pct", t_cube,
         f"cells={2 ** len(dims)};finest={cube_name(dims)}"))
 
-    # sharded IVM: steady-state tick + serving read over forced host meshes
+    # sharded IVM: steady-state tick + serving read per mesh size
     sharded = {}
     for ndev in SHARDED_DEVICE_COUNTS:
-        r = _run_sharded(ndev)
+        r = on_devices("benchmarks.bench_ivm", ndev, sharded_main)
         sharded[f"ndev{ndev}"] = r
         lines.append(row(
             f"ivm/sharded_tick_{ndev}dev", r["tick_us_sharded"] / 1e6,
@@ -216,7 +197,5 @@ def main():
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 1 and sys.argv[1] == "--sharded":
-        print(json.dumps(sharded_main(int(sys.argv[2])), sort_keys=True))
-    else:
+    if not devices_main(sys.argv, sharded_main):
         print("\n".join(main()))

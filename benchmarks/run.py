@@ -21,6 +21,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (bench_fig5_ablation, bench_ivm, bench_kernels,
                             bench_routing, bench_serving, bench_table2_views,
                             bench_table3_aggregates, bench_table45_training,

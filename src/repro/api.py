@@ -54,9 +54,9 @@ class ExecutionConfig:
     ``multi_root`` enables the paper's find-roots layer.
 
     Kernel blocking: ``block_size`` is the outer lax.scan row block,
-    ``block_rows`` the Pallas kernel row grid (a positive multiple of 8 —
-    the MXU sublane tile).  Either may be the string ``"auto"``: blocking is
-    then resolved per scan step by the compile-time autotuner
+    ``block_rows`` the Pallas kernel row grid (a positive multiple of 128 —
+    kernel rows ride the lane axis).  Either may be the string ``"auto"``:
+    blocking is then resolved per scan step by the compile-time autotuner
     (``core/autotune.py``), which times candidate grids against the step's
     signature and persists winners to an on-disk cache
     (``autotune_cache`` path > ``REPRO_AUTOTUNE_CACHE`` env >
@@ -111,7 +111,7 @@ class ExecutionConfig:
     block_size: object = 4096               # int | "auto"
     interpret: Optional[bool] = None
     fuse_scans: bool = True
-    block_rows: object = 512                # int (multiple of 8) | "auto"
+    block_rows: object = 512                # int (multiple of 128) | "auto"
     fuse_kernels: bool = True
     double_buffer: bool = True
     autotune_cache: Optional[str] = None

@@ -37,7 +37,7 @@ from repro.obs.trace import span
 
 DEFAULT_BLOCK_SIZE = 4096
 DEFAULT_BLOCK_ROWS = 512
-#: candidate grids — block_rows stays MXU-sublane aligned (multiples of 8)
+#: candidate grids — block_rows stays lane aligned (multiples of 128)
 BLOCK_SIZE_CANDIDATES = (1024, 4096, 16384)
 BLOCK_ROWS_CANDIDATES = (128, 256, 512, 1024)
 #: timing probes cap the row axis: above this the per-row cost is flat
@@ -107,7 +107,7 @@ def _valid_entry(e) -> bool:
     bs, br = e.get("block_size"), e.get("block_rows")
     if not isinstance(bs, int) or isinstance(bs, bool) or bs < 1:
         return False
-    if not isinstance(br, int) or isinstance(br, bool) or br < 8 or br % 8:
+    if not isinstance(br, int) or isinstance(br, bool) or br < 128 or br % 128:
         return False
     return True
 

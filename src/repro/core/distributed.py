@@ -24,8 +24,10 @@ from repro.core.plan import ExecutablePlan, _ceil_to
 
 
 def shard_columns(db, mesh: Mesh, axis: str, shard_rel: str):
-    """Pad the sharded relation to a multiple of the axis size and build the
-    per-relation column pytree + sharding specs."""
+    """Pad the sharded relation to a multiple of the axis size and place
+    every relation on the mesh — ``shard_rel`` row-partitioned, the rest
+    replicated — so a run reads each device's rows where they live.
+    Returns the per-relation column pytree and its sharding specs."""
     ndev = mesh.shape[axis]
     cols = {}
     specs = {}
@@ -33,12 +35,13 @@ def shard_columns(db, mesh: Mesh, axis: str, shard_rel: str):
         if name == shard_rel:
             n = rel.n_rows
             n_pad = _ceil_to(max(n, 1), ndev)
-            c = {a: jnp.pad(v, (0, n_pad - n)) if n_pad > n else v
-                 for a, v in rel.columns.items()}
-            cols[name] = c
-            specs[name] = {a: P(axis) for a in c}
+            cols[name] = {a: put_sharded(jnp.pad(v, (0, n_pad - n))
+                                         if n_pad > n else v, mesh, axis)
+                          for a, v in rel.columns.items()}
+            specs[name] = {a: P(axis) for a in rel.columns}
         else:
-            cols[name] = dict(rel.columns)
+            cols[name] = {a: put_replicated(v, mesh)
+                          for a, v in rel.columns.items()}
             specs[name] = {a: P() for a in rel.columns}
     return cols, specs
 
@@ -48,8 +51,6 @@ def sharded_runner(plan: ExecutablePlan, db, mesh: Mesh, axis: str, shard_rel: s
     """Build a jitted shard_map runner. Returns (fn, cols).  ``n_nodes`` is
     the param-batch (node) axis size for plans with batched params
     (DESIGN.md §7.4); batched view tensors psum with the node axis intact."""
-    from jax.experimental.shard_map import shard_map
-
     ndev = mesh.shape[axis]
     n_rows = db.sizes()
     cols, specs = shard_columns(db, mesh, axis, shard_rel)
@@ -63,8 +64,8 @@ def sharded_runner(plan: ExecutablePlan, db, mesh: Mesh, axis: str, shard_rel: s
                    psum_axes={shard_rel: axis})
 
     in_specs = (specs, P())
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                       check_vma=False)
     return jax.jit(fn), cols
 
 

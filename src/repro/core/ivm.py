@@ -489,7 +489,6 @@ class MaintainedBatch:
             self._init_runners[key] = jax.jit(
                 lambda c, p, nv: run(c, p, n_valid=nv))
             return self._init_runners[key]
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         mesh, axis, srel = self.mesh, self.mesh_axis, self.shard_rel
         col_specs = {name: {a: (P(axis) if name == srel else P())
@@ -501,9 +500,9 @@ class MaintainedBatch:
                    for name, v in nv.items()}
             return run(cols, p, n_valid=nvv, psum_axes={srel: axis})
 
-        self._init_runners[key] = jax.jit(shard_map(
+        self._init_runners[key] = jax.jit(jax.shard_map(
             local, mesh=mesh, in_specs=(col_specs, P(), nv_specs),
-            out_specs=P(), check_rep=False))
+            out_specs=P(), check_vma=False))
         return self._init_runners[key]
 
     def epoch_state(self, epoch: Optional[int] = None) -> EpochState:
@@ -876,7 +875,6 @@ class MaintainedBatch:
         later gather and the final ``state + delta`` fold read replicated
         values and the published epoch stays replicated (the soundness
         argument of DESIGN.md §8)."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core import distributed as dist
         mesh, axis, srel = self.mesh, self.mesh_axis, self.shard_rel
@@ -980,9 +978,9 @@ class MaintainedBatch:
                         P(), P(), P(), P(), P())
             out_specs = (P(), P(), P())
 
-        self._runners[key] = jax.jit(shard_map(
+        self._runners[key] = jax.jit(jax.shard_map(
             run, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False))
+            check_vma=False))
         return self._runners[key]
 
     # -- explain/serve introspection -----------------------------------------

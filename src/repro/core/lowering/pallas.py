@@ -4,7 +4,8 @@ Rows stream through ``lax.scan`` in ``PlanConfig.block_size`` blocks (same
 bounded-memory structure as the XLA backend — payloads never materialize
 beyond one block), but each block's reduction runs through the one-hot-matmul
 kernels — the TPU-native form of the multi-output trie scan, with the dense
-view accumulators pinned in VMEM across the kernel's row grid.
+view accumulators held in VMEM one segment tile at a time across the
+kernel's row grid.
 
 Launch fusion (``PlanConfig.fuse_kernels``, default): the **union of a
 step's reductions** — every local group-by bucket *and* every histogram-
@@ -38,10 +39,13 @@ from repro.core.ir import StepProgram, ViewProgram
 from repro.core.lowering import common
 
 
-def _resolve_interpret(config) -> bool:
+def resolve_interpret(config, platform=None) -> bool:
+    """Whether the kernels run in interpret mode under ``config``: its
+    ``interpret`` when set, else on every platform but the TPU
+    (``platform`` defaults to JAX's default backend)."""
     if config.interpret is not None:
         return bool(config.interpret)
-    return jax.default_backend() != "tpu"
+    return (platform or jax.default_backend()) != "tpu"
 
 
 def _step_split(prog: StepProgram):
@@ -82,7 +86,7 @@ class PallasBackend:
         valid-row counts of capacity-padded resident relations)."""
         from repro.kernels import ops
 
-        interpret = _resolve_interpret(config)
+        interpret = resolve_interpret(config)
         block_size = (config.block_size if isinstance(config.block_size, int)
                       else DEFAULT_BLOCK_SIZE)
         block_rows = (config.block_rows if isinstance(config.block_rows, int)

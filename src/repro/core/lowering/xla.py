@@ -56,8 +56,8 @@ class XlaBackend:
 
             gathered = common.gather_children(prog.gathers, blk_cols, arrays, B)
 
-            new_accs = []
-            for vp, acc in zip(prog.views, accs):
+            contribs = []
+            for vp in prog.views:
                 payload = common.view_payload(vp, blk_cols, gathered, params,
                                               valid, B, n_nodes)
                 if vp.seg is not None:
@@ -73,8 +73,13 @@ class XlaBackend:
                             payload, seg, num_segments=vp.seg.n_segments)
                 else:
                     contrib = payload.sum(axis=1 if vp.batched else 0)
-                new_accs.append(acc + contrib)
-            return tuple(new_accs), None
+                contribs.append(contrib)
+            # the block's partial sums are formed apart from the carried
+            # accumulators: XLA would otherwise fold ``acc + segment_sum``
+            # into one scatter-add onto ``acc``, adding rows one at a time
+            # to the running f32 total (a COUNT stalls at 2^24 on the TPU)
+            contribs = jax.lax.optimization_barrier(tuple(contribs))
+            return tuple(a + c for a, c in zip(accs, contribs)), None
 
         accs, _ = jax.lax.scan(body, accs, (cols_blocked, iota))
 

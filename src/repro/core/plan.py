@@ -56,11 +56,11 @@ def validate_blocking(block_size, block_rows) -> None:
                          f"got {block_size!r}")
     if block_rows != "auto" and (
             not isinstance(block_rows, int) or isinstance(block_rows, bool)
-            or block_rows < 1 or block_rows % 8):
-        raise ValueError("block_rows must be a positive multiple of 8 (the "
-                         "MXU sublane tile: kernel row blocks below/off that "
-                         f"alignment cannot be lowered) or 'auto'; got "
-                         f"{block_rows!r}")
+            or block_rows < 1 or block_rows % 128):
+        raise ValueError("block_rows must be a positive multiple of 128 (the "
+                         "lane tile: kernel rows ride the lane axis, and row "
+                         f"blocks off that alignment cannot be lowered) or "
+                         f"'auto'; got {block_rows!r}")
 
 
 @dataclasses.dataclass
@@ -188,11 +188,10 @@ class ExecutablePlan:
         return out
 
     def _interpret_flag(self, platform: str) -> bool:
-        cfg = self.config
-        if cfg.backend != "pallas":
-            return False
-        return (bool(cfg.interpret) if cfg.interpret is not None
-                else platform != "tpu")
+        from repro.core.lowering.pallas import resolve_interpret
+
+        return (self.config.backend == "pallas"
+                and resolve_interpret(self.config, platform))
 
     def _prog_tune_dims(self, prog: StepProgram, n_nodes: Optional[int]):
         """(widest segment layout, total payload width) of one fused step —

@@ -52,7 +52,8 @@ def _bucketize(x: np.ndarray, n: int = N_BUCKETS) -> Tuple[np.ndarray, np.ndarra
     return np.searchsorted(qs, x).astype(np.int32), qs.astype(np.float32)
 
 
-def _zipf_codes(rng, n, domain, a=1.3):
+def zipf_codes(rng, n, domain, a=1.3):
+    """``n`` skewed key codes in ``[0, domain)``: Zipf(a), folded."""
     z = rng.zipf(a, size=n)
     return ((z - 1) % domain).astype(np.int32)
 
@@ -91,8 +92,8 @@ def make_favorita(scale: float = 1.0, seed: int = 0) -> Dataset:
     ])
 
     date = rng.integers(0, n_date, n_fact).astype(np.int32)
-    store = _zipf_codes(rng, n_fact, n_store)
-    item = _zipf_codes(rng, n_fact, n_item)
+    store = zipf_codes(rng, n_fact, n_store)
+    item = zipf_codes(rng, n_fact, n_item)
     promo = rng.integers(0, 2, n_fact).astype(np.int32)
     txns = np.maximum(1.0, rng.normal(1000, 300, n_date * n_store)).astype(np.float32)
     txns_b, _ = _bucketize(txns)
@@ -182,8 +183,8 @@ def make_retailer(scale: float = 1.0, seed: int = 1) -> Dataset:
     cat_of = rng.integers(0, 10, n_sku).astype(np.int32)
     cat_eff = rng.normal(0, 5.0, 10).astype(np.float32)
     f_date = rng.integers(0, n_date, n_fact).astype(np.int32)
-    f_locn = _zipf_codes(rng, n_fact, n_locn)
-    f_sku = _zipf_codes(rng, n_fact, n_sku)
+    f_locn = zipf_codes(rng, n_fact, n_locn)
+    f_sku = zipf_codes(rng, n_fact, n_sku)
     inv = (12.0 + 0.0004 * pop[zip_of[f_locn]] + cat_eff[cat_of[f_sku]]
            + 0.1 * maxtemp[f_date * n_locn + f_locn] - 0.2 * prize[f_sku]
            + rng.normal(0, 4.0, n_fact)).astype(np.float32)
@@ -264,8 +265,8 @@ def make_yelp(scale: float = 1.0, seed: int = 2) -> Dataset:
     b_stars_b, _ = _bucketize(b_stars)
 
     tables = {
-        "Review": {"user": _zipf_codes(rng, n_fact, n_user),
-                   "business": _zipf_codes(rng, n_fact, n_biz),
+        "Review": {"user": zipf_codes(rng, n_fact, n_user),
+                   "business": zipf_codes(rng, n_fact, n_biz),
                    "stars": stars, "stars__b": stars_b,
                    "useful": np.abs(rng.normal(2, 2, n_fact)).astype(np.float32)},
         "User": {"user": np.arange(n_user, dtype=np.int32),
@@ -355,7 +356,7 @@ def make_tpcds(scale: float = 1.0, seed: int = 3) -> Dataset:
     logit = -0.6 + 0.45 * (educ[cd_of] - 3) + 0.12 * (inc[hd_of] - 10)
     c_pref = (rng.random(n_cust) < 1 / (1 + np.exp(-logit))).astype(np.int32)
     # quantity depends on item price, promo channel, and sales price
-    f_item = _zipf_codes(rng, n_fact, n_item)
+    f_item = zipf_codes(rng, n_fact, n_item)
     f_promo = rng.integers(0, n_promo, n_fact).astype(np.int32)
     ch_of = rng.integers(0, 4, n_promo).astype(np.int32)
     ch_eff = np.array([0.0, 2.0, 4.0, -1.5], dtype=np.float32)
@@ -367,7 +368,7 @@ def make_tpcds(scale: float = 1.0, seed: int = 3) -> Dataset:
         "store_sales": {"d_date_sk": rng.integers(0, n_date, n_fact).astype(np.int32),
                         "t_time_sk": rng.integers(0, n_time, n_fact).astype(np.int32),
                         "i_item_sk": f_item,
-                        "c_customer_sk": _zipf_codes(rng, n_fact, n_cust),
+                        "c_customer_sk": zipf_codes(rng, n_fact, n_cust),
                         "s_store_sk": rng.integers(0, n_store, n_fact).astype(np.int32),
                         "p_promo_sk": f_promo,
                         "ss_quantity": qty, "ss_quantity__b": qty_b,

@@ -44,17 +44,11 @@ def covar_xtx(x: jnp.ndarray, w: Optional[jnp.ndarray] = None, *,
 @functools.partial(jax.jit, static_argnames=("n_segments", "block_rows", "interpret"))
 def seg_aggregate(seg: jnp.ndarray, payload: jnp.ndarray, n_segments: int, *,
                   block_rows: int = 512, interpret: bool = False) -> jnp.ndarray:
-    """Segment-sum payload rows into n_segments (padding rows -> id n_segments,
-    accumulated into a sacrificial extra row then dropped)."""
-    n, a = payload.shape
-    segp = _pad_rows(seg.astype(jnp.int32), block_rows)
-    pad = segp.shape[0] - n
-    if pad:
-        segp = segp.at[n:].set(n_segments)
-    payp = _pad_rows(payload.astype(jnp.float32), block_rows)
-    out = seg_aggregate_pallas(segp, payp, n_segments + 1,
-                               block_rows=block_rows, interpret=interpret)
-    return out[:n_segments]
+    """Segment-sum payload rows into n_segments (ids outside
+    ``[0, n_segments)`` contribute nowhere)."""
+    return seg_aggregate_pallas(seg.astype(jnp.int32),
+                                payload.astype(jnp.float32), n_segments,
+                                block_rows=block_rows, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("n_buckets", "block_rows", "interpret"))
@@ -62,16 +56,8 @@ def tree_hist(codes: jnp.ndarray, y: jnp.ndarray, cond: jnp.ndarray,
               n_buckets: int, *, block_rows: int = 512,
               interpret: bool = False) -> jnp.ndarray:
     """Per-bucket [count, Σy, Σy²] under the node mask."""
-    n = codes.shape[0]
-    codesp = _pad_rows(codes.astype(jnp.int32), block_rows)
-    pad = codesp.shape[0] - n
-    if pad:
-        codesp = codesp.at[n:].set(n_buckets)  # out-of-range -> sacrificial row
-    yp = _pad_rows(y.astype(jnp.float32), block_rows)
-    condp = _pad_rows(cond.astype(jnp.float32), block_rows)
-    out = tree_hist_pallas(codesp, yp, condp, n_buckets + 1,
-                           block_rows=block_rows, interpret=interpret)
-    return out[:n_buckets]
+    return tree_hist_pallas(codes, y, cond.astype(jnp.float32), n_buckets,
+                            block_rows=block_rows, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("n_buckets", "block_rows", "interpret"))
@@ -80,9 +66,7 @@ def tree_hist_batched(codes: jnp.ndarray, y: jnp.ndarray, cond: jnp.ndarray,
                       interpret: bool = False) -> jnp.ndarray:
     """Per-node, per-bucket [count, Σy, Σy²]: ``cond`` is (n, N) — one mask
     column per frontier node — and the result is (N, n_buckets, 3), computed
-    in one fused kernel pass over the rows (DESIGN.md §7.4).  No sacrificial
-    bucket: the kernel zero-pads ``cond``, so padded rows contribute nothing
-    wherever their codes land."""
+    in one fused kernel pass over the rows (DESIGN.md §7.4)."""
     return tree_hist_batched_pallas(codes.astype(jnp.int32),
                                     y.astype(jnp.float32),
                                     cond.astype(jnp.float32), n_buckets,

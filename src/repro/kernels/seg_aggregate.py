@@ -4,59 +4,29 @@ One pass over a relation block computes *all* aggregate columns of a view
 group keyed by a (flattened) group-by code: the TPU-native form of LMFAO's
 multi-output trie scan.  The scatter-accumulate is expressed as a one-hot
 matmul ``onehot(seg)ᵀ @ payload`` so it runs on the MXU instead of a serial
-scatter; the dense ``(S, A)`` view accumulator is pinned in VMEM across the
-grid (views are small relative to fact tables — paper Table 2 — so they fit).
+scatter.  It is the fused scan-block kernel with one "seg" reduction, so it
+shares that kernel's lane-major layout and segment tiling: the view
+accumulator is held in VMEM one segment tile at a time.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.padding import pad_rows as _pad_rows
-
-
-def _seg_kernel(seg_ref, pay_ref, o_ref, acc_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    seg = seg_ref[...]                             # (bm, 1) int32
-    pay = pay_ref[...]                             # (bm, A)
-    s = acc_ref.shape[0]
-    onehot = (seg == jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)).astype(jnp.float32)
-    acc_ref[...] += jnp.dot(onehot.T, pay, preferred_element_type=jnp.float32)
-
-    @pl.when(i == pl.num_programs(0) - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...]
+from repro.kernels.fused_scan import ReduceSpec, fused_scan_block_pallas
 
 
 def seg_aggregate_pallas(seg: jnp.ndarray, payload: jnp.ndarray, n_segments: int,
                          *, block_rows: int = 512, interpret: bool = False) -> jnp.ndarray:
     """out[s, a] = Σ_{n: seg[n]=s} payload[n, a].
 
-    seg: (N,) int32 in [0, n_segments) (out-of-range rows contribute nowhere —
-    the ops wrapper uses seg = n_segments for padding); payload: (N, A) f32.
-    Rows are padded to a ``block_rows`` multiple with zeroed payload (padded
-    rows land in segment 0 but contribute 0), so any N works."""
+    seg: (N,) int32 in [0, n_segments) (out-of-range rows contribute
+    nowhere); payload: (N, A) f32.  Rows are padded to a ``block_rows``
+    multiple with zeroed payload, so any N works."""
     assert seg.shape == (payload.shape[0],)
-    seg = _pad_rows(seg.astype(jnp.int32), block_rows)
-    payload = _pad_rows(payload, block_rows)
-    n, a = payload.shape
-    return pl.pallas_call(
-        _seg_kernel,
-        grid=(n // block_rows,),
-        in_specs=[
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, a), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((n_segments, a), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_segments, a), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((n_segments, a), jnp.float32)],
-        interpret=interpret,
-    )(seg.reshape(n, 1).astype(jnp.int32), payload)
+    spec = ReduceSpec("seg", 0, n_segments, payload.shape[1], 0)
+    (out,) = fused_scan_block_pallas(
+        seg.astype(jnp.int32)[:, None], payload, (spec,),
+        block_rows=block_rows, interpret=interpret, double_buffer=False,
+        name="seg_aggregate")
+    return out

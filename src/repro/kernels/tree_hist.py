@@ -8,92 +8,43 @@ so the row block is read once from VMEM.
 
 The batched variant evaluates a whole *node frontier* at once: ``cond`` is
 ``(n, N)`` — one mask column per tree node — and the kernel forms the
-``(bm, N·3)`` payload ``cond ⊗ [1, y, y²]`` before a single one-hot matmul,
-so the MXU contraction is shared across all ``N`` nodes and the accumulator
-(``(D, N·3)`` in VMEM, returned as ``(N, D, 3)``) stays resident across the
-row grid (DESIGN.md §7.4).
+payload ``cond ⊗ [1, y, y²]`` before the one-hot matmuls, so one pass over
+the rows serves all ``N`` nodes (DESIGN.md §7.4).
 
-Arbitrary row counts are handled by padding the row axis with zeroed ``cond``
-inside the wrappers here (padded rows contribute nothing), so callers never
-need ``n % block_rows == 0``.
+Both are the fused scan-block kernel with one "hist" reduction.  Arbitrary
+row counts are handled by padding the row axis with zeroed ``cond`` (padded
+rows contribute nothing), so callers never need ``n % block_rows == 0``.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.padding import pad_rows as _pad_rows
+from repro.kernels.fused_scan import ReduceSpec, fused_scan_block_pallas
 
 
-def _hist_kernel(code_ref, y_ref, cond_ref, o_ref, acc_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    code = code_ref[...]                      # (bm, 1) int32 bucket codes
-    y = y_ref[...]                            # (bm, 1)
-    cond = cond_ref[...]                      # (bm, 1) node mask in {0,1}
-    payload = jnp.concatenate([cond, cond * y, cond * y * y], axis=1)  # (bm, 3)
-    d = acc_ref.shape[0]
-    onehot = (code == jax.lax.broadcasted_iota(jnp.int32, (1, d), 1)).astype(jnp.float32)
-    acc_ref[...] += jnp.dot(onehot.T, payload, preferred_element_type=jnp.float32)
-
-    @pl.when(i == pl.num_programs(0) - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...]
+def _hist(codes, y, cond, n_buckets, block_rows, interpret, name):
+    """(n_buckets, N·3) histogram of ``cond (n, N)`` columns."""
+    n_cond = cond.shape[1]
+    y = y.astype(jnp.float32)
+    fpay = jnp.concatenate(
+        [cond.astype(jnp.float32),
+         jnp.stack([jnp.ones_like(y), y, y * y], axis=1)], axis=1)
+    spec = ReduceSpec("hist", 0, n_buckets, 3 * n_cond, 0, n_cond=n_cond,
+                      yk_off=n_cond)
+    (out,) = fused_scan_block_pallas(
+        codes.astype(jnp.int32)[:, None], fpay, (spec,),
+        block_rows=block_rows, interpret=interpret, double_buffer=False,
+        name=name)
+    return out
 
 
 def tree_hist_pallas(codes: jnp.ndarray, y: jnp.ndarray, cond: jnp.ndarray,
                      n_buckets: int, *, block_rows: int = 512,
                      interpret: bool = False) -> jnp.ndarray:
-    """out[b] = [Σ cond, Σ cond·y, Σ cond·y²] over rows with codes==b.
-
-    Rows are padded to a ``block_rows`` multiple with zeroed ``cond`` (padded
-    rows contribute nothing), so any ``n`` works."""
-    codes = _pad_rows(codes.astype(jnp.int32), block_rows)
-    y = _pad_rows(y, block_rows)
-    cond = _pad_rows(cond, block_rows)   # zero-pad: dead rows
-    n = codes.shape[0]
-    return pl.pallas_call(
-        _hist_kernel,
-        grid=(n // block_rows,),
-        in_specs=[
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((n_buckets, 3), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_buckets, 3), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((n_buckets, 3), jnp.float32)],
-        interpret=interpret,
-    )(codes.reshape(n, 1), y.reshape(n, 1), cond.reshape(n, 1))
-
-
-def _hist_batched_kernel(code_ref, yk_ref, cond_ref, o_ref, acc_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    code = code_ref[...]                      # (bm, 1) int32 bucket codes
-    yk = yk_ref[...]                          # (bm, 3) = [1, y, y²]
-    cond = cond_ref[...]                      # (bm, N) node masks
-    bm, n_nodes = cond.shape
-    # payload[r, j*3 + k] = cond[r, j] * yk[r, k]  — the N·3 aggregate columns
-    payload = (cond[:, :, None] * yk[:, None, :]).reshape(bm, n_nodes * 3)
-    d = acc_ref.shape[0]
-    onehot = (code == jax.lax.broadcasted_iota(jnp.int32, (1, d), 1)).astype(jnp.float32)
-    acc_ref[...] += jnp.dot(onehot.T, payload, preferred_element_type=jnp.float32)
-
-    @pl.when(i == pl.num_programs(0) - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...]
+    """out[b] = [Σ cond, Σ cond·y, Σ cond·y²] over rows with codes==b."""
+    return _hist(codes, y, cond[:, None], n_buckets, block_rows, interpret,
+                 "tree_hist")
 
 
 def tree_hist_batched_pallas(codes: jnp.ndarray, y: jnp.ndarray,
@@ -101,28 +52,9 @@ def tree_hist_batched_pallas(codes: jnp.ndarray, y: jnp.ndarray,
                              block_rows: int = 512,
                              interpret: bool = False) -> jnp.ndarray:
     """out[j, b] = [Σ cond_j, Σ cond_j·y, Σ cond_j·y²] over rows with
-    codes==b, for every node column j of ``cond`` (shape (n, N)).
-
-    One fused pass serves the entire node frontier: the accumulator is kept
-    as (D, N·3) in VMEM (MXU-friendly one-hot matmul batched over nodes) and
-    reshaped to (N, D, 3) on return."""
+    codes==b, for every node column j of ``cond`` (shape (n, N)); returned
+    as (N, n_buckets, 3)."""
     n_nodes = cond.shape[1]
-    codes = _pad_rows(codes.astype(jnp.int32), block_rows)
-    n = codes.shape[0]
-    yp = _pad_rows(y.astype(jnp.float32), block_rows)
-    condp = _pad_rows(cond.astype(jnp.float32), block_rows)  # zero: dead rows
-    yk = jnp.stack([jnp.ones_like(yp), yp, yp * yp], axis=1)  # (n, 3)
-    out = pl.pallas_call(
-        _hist_batched_kernel,
-        grid=(n // block_rows,),
-        in_specs=[
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, 3), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, n_nodes), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((n_buckets, n_nodes * 3), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_buckets, n_nodes * 3), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((n_buckets, n_nodes * 3), jnp.float32)],
-        interpret=interpret,
-    )(codes.reshape(n, 1), yk, condp)
+    out = _hist(codes, y, cond, n_buckets, block_rows, interpret,
+                "tree_hist_batched")
     return jnp.transpose(out.reshape(n_buckets, n_nodes, 3), (1, 0, 2))

@@ -156,6 +156,39 @@ def test_fused_scan_block_batched_cond(n, n_cond):
                                    rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("double_buffer", [True, False])
+def test_fused_scan_block_segment_tiles(double_buffer):
+    """Reductions wider than one VMEM segment tile split over the grid's
+    tile axis; a narrow reduction in the same launch sits out later tiles,
+    and a wide hist owns fewer tiles than the widest reduction."""
+    from repro.kernels.fused_scan import segment_tile
+    n, br = 1500, 1024
+    rng = np.random.default_rng(3)
+    S1, W1, S2, W2, D, nc = 3000, 3, 40, 2, 2000, 2
+    codes = np.stack([rng.integers(0, S1, n), rng.integers(0, S2, n),
+                      rng.integers(0, D, n)], axis=1).astype(np.int32)
+    y = rng.normal(size=n).astype(np.float32)
+    fpay = np.concatenate(
+        [rng.normal(size=(n, W1 + W2)).astype(np.float32),
+         (rng.random((n, nc)) < 0.5).astype(np.float32),
+         np.stack([np.ones(n, np.float32), y, y * y], axis=1)], axis=1)
+    specs = (ops.ReduceSpec("seg", 0, S1, W1, 0),
+             ops.ReduceSpec("seg", 1, S2, W2, W1),
+             ops.ReduceSpec("hist", 2, D, 3 * nc, W1 + W2, n_cond=nc,
+                            yk_off=W1 + W2 + nc))
+    tile = segment_tile(specs, 8, 8, br)
+    assert D > tile and S1 > 2 * tile
+    got = ops.fused_scan_block(jnp.asarray(codes), jnp.asarray(fpay), specs,
+                               block_rows=br, interpret=True,
+                               double_buffer=double_buffer)
+    want = ref.fused_scan_block_ref(jnp.asarray(codes), jnp.asarray(fpay),
+                                    specs)
+    for sp, g, w in zip(specs, got, want):
+        assert g.shape == (sp.n_segments, sp.width)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4, err_msg=str(sp))
+
+
 def test_fused_scan_block_dbuf_matches_grid_bitwise():
     """The two-slot DMA pipeline is a pure data-movement change: it must be
     bit-identical to the grid-pipelined path, not merely close."""
