@@ -19,10 +19,10 @@ What it measures (``JSON_PAYLOAD`` → ``BENCH_serving.json`` via
   reader errors, one recorded workload signature per served view, and a
   non-degenerate latency distribution.
 
-Telemetry is ON for the whole run (tracing + metrics + workload recorder)
-— the harness doubles as the regression net for the no-sync rule: a chrome
-trace sample is exported (``BENCH_SERVING_TRACE`` env, default
-``trace_serving.json``) for CI to archive.
+Metrics and the workload recorder are ON for the whole run — the harness
+doubles as the regression net for the no-sync rule.  The engine's spans go
+to the JAX profiler's trace (DESIGN.md §11): run it under
+``jax.profiler.trace`` to see them beside the device's operations.
 
     PYTHONPATH=src python -m benchmarks.bench_serving
 """
@@ -67,8 +67,6 @@ def main():
     rng = np.random.default_rng(7)
     n_ticks = _n_ticks()
 
-    obs.clear_trace()
-    obs.enable_tracing()
     olr = OnlineRidge(ds)
     olr.fit()
     srv = olr.view.serve(max_pinned_epochs=MAX_PINNED, warn_epoch_lag=2)
@@ -133,10 +131,6 @@ def main():
 
     stats = srv.stats()
     rh = read_hist.snapshot()
-    trace_path = os.environ.get("BENCH_SERVING_TRACE", "trace_serving.json")
-    obs.export_chrome(trace_path)
-    n_trace_events = len(obs.get_tracer().events())
-    obs.disable_tracing()
     wl = workload.by_signature()
     served_sigs = sum(1 for e in wl.values()
                       if "pinned_read" in e["hits"])
@@ -165,7 +159,6 @@ def main():
         "n_reader_errors": len(errors),
         "served_view_signatures": int(served_sigs),
         "n_served_views": len(olr.view.names),
-        "trace_events": int(n_trace_events),
         "errors": errors,
     })
     return [

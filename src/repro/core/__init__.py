@@ -14,6 +14,13 @@ from repro.core.jointree import JoinTree, materialize_bag
 from repro.core.schema import (Attribute, DatabaseSchema, RelationSchema,
                                CATEGORICAL, CONTINUOUS, KEY, schema)
 
+# the engine's spans (repro.obs.trace, which imports no jax) go to the JAX
+# profiler's trace: every module that opens one imports this package first
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+from repro.obs.trace import install as _install_spans
+
+_install_spans(_TraceAnnotation)
+
 # NOTE: the IVM subsystem (repro.core.ivm: MaintainedBatch, DeltaProgram) is
 # deliberately not imported here — it depends on repro.data.relations, which
 # imports repro.core.schema, and an eager import would cycle whenever

@@ -71,6 +71,19 @@ class BatchStats:
                 f"launches={self.n_kernel_launches}")
 
 
+def _jit_batch(run):
+    """``run`` jitted as the program ``aggregate_batch``: the name a
+    profiler trace gives its module and every op name it carries
+    (``jit(aggregate_batch)/scan.Sales/...``).  The persistent
+    compilation cache keys a program without its op names, so an
+    executable it holds keeps the op names it was compiled with."""
+
+    def aggregate_batch(c, p):
+        return run(c, p)
+
+    return jax.jit(aggregate_batch)
+
+
 class CompiledBatch:
     def __init__(self, schema: DatabaseSchema, tree: JoinTree,
                  result: PushdownResult, groups: List[ViewGroup],
@@ -117,7 +130,7 @@ class CompiledBatch:
         key = ("local", tuple(sorted(n_rows.items())), tuple(sorted(params)))
         if key not in self._jitted:
             run = self.plan.bind(n_rows)
-            self._jitted[key] = jax.jit(lambda cols, p: run(cols, p))
+            self._jitted[key] = _jit_batch(run)
         cols = {name: dict(rel.columns) for name, rel in db.relations.items()}
         self.n_dispatches += 1
         return self._jitted[key](cols, params)
@@ -167,7 +180,7 @@ class CompiledBatch:
                tuple(sorted(params)))
         if key not in self._jitted:
             run = self.plan.bind(n_rows, n_nodes=n_run)
-            self._jitted[key] = jax.jit(lambda cols, p: run(cols, p))
+            self._jitted[key] = _jit_batch(run)
         cols = {name: dict(rel.columns) for name, rel in db.relations.items()}
         self.n_dispatches += 1
         out = self._jitted[key](cols, params)
@@ -189,7 +202,7 @@ class CompiledBatch:
                 for name, rel in db.relations.items()}
         pspec = {k: jax.ShapeDtypeStruct(jnp.shape(v), jnp.asarray(v).dtype)
                  for k, v in params.items()}
-        return jax.jit(lambda c, p: run(c, p)).lower(cols, pspec)
+        return _jit_batch(run).lower(cols, pspec)
 
     # -- domain-parallel (paper layer 7 on a chip mesh) ----------------------
 

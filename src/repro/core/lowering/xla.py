@@ -54,34 +54,45 @@ class XlaBackend:
             blk_cols, valid = common.block_validity(
                 dict(blk_cols), blk_i, B, n_pad, n_valid, offset)
 
-            gathered = common.gather_children(prog.gathers, blk_cols, arrays, B)
+            # named scopes put the part of the block each op comes from
+            # into its op name; they change no op
+            with jax.named_scope("gather"):
+                gathered = common.gather_children(prog.gathers, blk_cols,
+                                                  arrays, B)
 
             contribs = []
             for vp in prog.views:
-                payload = common.view_payload(vp, blk_cols, gathered, params,
-                                              valid, B, n_nodes)
-                if vp.seg is not None:
-                    seg = common.segment_ids(blk_cols, vp.seg)
-                    if vp.batched:
-                        # segment_sum reduces axis 0: rows forward, node
-                        # axis back, then restore the leading node axis
-                        contrib = jnp.swapaxes(jax.ops.segment_sum(
-                            jnp.swapaxes(payload, 0, 1), seg,
-                            num_segments=vp.seg.n_segments), 0, 1)
-                    else:
-                        contrib = jax.ops.segment_sum(
-                            payload, seg, num_segments=vp.seg.n_segments)
-                else:
-                    contrib = payload.sum(axis=1 if vp.batched else 0)
-                contribs.append(contrib)
+                with jax.named_scope("payload"):
+                    payload = common.view_payload(vp, blk_cols, gathered,
+                                                  params, valid, B, n_nodes)
+                with jax.named_scope("partials"):
+                    contribs.append(_partials(vp, payload, blk_cols))
             # the block's partial sums are formed apart from the carried
             # accumulators: XLA would otherwise fold ``acc + segment_sum``
             # into one scatter-add onto ``acc``, adding rows one at a time
             # to the running f32 total (a COUNT stalls at 2^24 on the TPU)
             contribs = jax.lax.optimization_barrier(tuple(contribs))
-            return tuple(a + c for a, c in zip(accs, contribs)), None
+            with jax.named_scope("accumulate"):
+                return tuple(a + c for a, c in zip(accs, contribs)), None
 
         accs, _ = jax.lax.scan(body, accs, (cols_blocked, iota))
 
-        for vp, acc in zip(prog.views, accs):
-            arrays[vp.vid] = common.finalize(vp, acc)
+        with jax.named_scope("finalize"):
+            for vp, acc in zip(prog.views, accs):
+                arrays[vp.vid] = common.finalize(vp, acc)
+
+
+def _partials(vp, payload: jnp.ndarray, blk_cols) -> jnp.ndarray:
+    """One block's contribution to a view: ``segment_sum`` over the view's
+    local group-by (a zero-filled partial per block), or the axis sum of a
+    scalar or pulled-only view."""
+    if vp.seg is None:
+        return payload.sum(axis=1 if vp.batched else 0)
+    seg = common.segment_ids(blk_cols, vp.seg)
+    if vp.batched:
+        # segment_sum reduces axis 0: rows forward, node axis back, then
+        # restore the leading node axis
+        return jnp.swapaxes(jax.ops.segment_sum(
+            jnp.swapaxes(payload, 0, 1), seg,
+            num_segments=vp.seg.n_segments), 0, 1)
+    return jax.ops.segment_sum(payload, seg, num_segments=vp.seg.n_segments)

@@ -335,31 +335,35 @@ class ExecutablePlan:
         arrays: Dict[int, jnp.ndarray] = {}
         for step, prog, cfg in zip(self.schedule.steps, self.step_programs,
                                    step_configs):
-            self.backend.run_step(
-                prog, columns[step.rel], arrays, params,
-                n_valid=n_rows[step.rel],
-                offset=offsets.get(step.rel, 0), config=cfg,
-                n_nodes=n_nodes)
-            if step.rel in psum_axes:
-                for vid in step.vids:
-                    arrays[vid] = jax.lax.psum(arrays[vid],
-                                               psum_axes[step.rel])
+            # every op of the step carries its relation in its op name
+            with jax.named_scope(f"scan.{step.rel}"):
+                self.backend.run_step(
+                    prog, columns[step.rel], arrays, params,
+                    n_valid=n_rows[step.rel],
+                    offset=offsets.get(step.rel, 0), config=cfg,
+                    n_nodes=n_nodes)
+                if step.rel in psum_axes:
+                    for vid in step.vids:
+                        arrays[vid] = jax.lax.psum(arrays[vid],
+                                                   psum_axes[step.rel])
         return arrays
 
     def extract_outputs(self, arrays: Mapping[int, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
         """Read query results out of view arrays (column select + transpose
         from canonical to user group-by order)."""
         out = {}
-        for qname, qo in self.result.outputs.items():
-            arr = arrays[qo.vid]
-            cols = jnp.take(arr, jnp.asarray(qo.cols), axis=-1)
-            # canonical axis order -> user group-by order; a leading node
-            # axis (batched outputs) stays in front
-            lead = 1 if qo.vid in self.batched_vids else 0
-            perm = [qo.canonical_group_by.index(a) + lead
-                    for a in qo.query.group_by]
-            perm = list(range(lead)) + perm + [lead + len(qo.query.group_by)]
-            out[qname] = jnp.transpose(cols, perm)
+        with jax.named_scope("outputs"):
+            for qname, qo in self.result.outputs.items():
+                arr = arrays[qo.vid]
+                cols = jnp.take(arr, jnp.asarray(qo.cols), axis=-1)
+                # canonical axis order -> user group-by order; a leading
+                # node axis (batched outputs) stays in front
+                lead = 1 if qo.vid in self.batched_vids else 0
+                perm = [qo.canonical_group_by.index(a) + lead
+                        for a in qo.query.group_by]
+                perm = (list(range(lead)) + perm
+                        + [lead + len(qo.query.group_by)])
+                out[qname] = jnp.transpose(cols, perm)
         return out
 
 

@@ -56,5 +56,5 @@ def covar_xtx_pallas(x: jnp.ndarray, w: jnp.ndarray, *, block_rows: int = 512,
         out_specs=pl.BlockSpec((f, f), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((f, f), jnp.float32),
         scratch_shapes=[pltpu.VMEM((f, f), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="covar_xtx",
     )(x, w.reshape(n, 1))

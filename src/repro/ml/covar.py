@@ -19,6 +19,7 @@ from repro.api import Database, ExecutionConfig, connect
 from repro.core import COUNT, Var, agg, query, sum_of, sum_prod
 from repro.core.aggregates import Query
 from repro.data.datasets import Dataset
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass
@@ -84,38 +85,39 @@ def covar_queries(ds: Dataset, cont: Optional[Sequence[str]] = None,
 def assemble_covar(outputs: Dict[str, np.ndarray], layout: CovarLayout) -> Tuple[np.ndarray, float]:
     """Dense symmetric (p, p) covar matrix + dataset size N from the batch
     outputs (the application layer is cheap: paper §1)."""
-    p = layout.p
-    C = np.zeros((p, p), dtype=np.float64)
-    xs = list(layout.cont) + [layout.label]
-    xidx = [layout.cont_idx(x) for x in layout.cont] + [layout.label_idx]
+    with span("ml.covar.assemble"):
+        p = layout.p
+        C = np.zeros((p, p), dtype=np.float64)
+        xs = list(layout.cont) + [layout.label]
+        xidx = [layout.cont_idx(x) for x in layout.cont] + [layout.label_idx]
 
-    sc = np.asarray(outputs["cm_scalar"], dtype=np.float64)
-    N = float(sc[0])
-    C[0, 0] = N
-    for i, xi in enumerate(xs):
-        C[0, xidx[i]] = C[xidx[i], 0] = sc[1 + i]
-    k = 1 + len(xs)
-    for i in range(len(xs)):
-        for j in range(i, len(xs)):
-            C[xidx[i], xidx[j]] = C[xidx[j], xidx[i]] = sc[k]
-            k += 1
-
-    for c in layout.cat:
-        out = np.asarray(outputs[f"cm_cat_{c}"], dtype=np.float64)  # (D, 1+len(xs))
-        sl = layout.cat_slice(c)
-        cnt = out[:, 0]
-        C[sl, 0] = C[0, sl] = cnt
-        np.fill_diagonal(C[sl, sl], cnt)  # one-hot: Xc·Xc = diag(count)
+        sc = np.asarray(outputs["cm_scalar"], dtype=np.float64)
+        N = float(sc[0])
+        C[0, 0] = N
         for i, xi in enumerate(xs):
-            C[sl, xidx[i]] = out[:, 1 + i]
-            C[xidx[i], sl] = out[:, 1 + i]
+            C[0, xidx[i]] = C[xidx[i], 0] = sc[1 + i]
+        k = 1 + len(xs)
+        for i in range(len(xs)):
+            for j in range(i, len(xs)):
+                C[xidx[i], xidx[j]] = C[xidx[j], xidx[i]] = sc[k]
+                k += 1
 
-    for i, ci in enumerate(layout.cat):
-        for ck in layout.cat[i + 1:]:
-            out = np.asarray(outputs[f"cm_cat2_{ci}_{ck}"], dtype=np.float64)[..., 0]
-            C[layout.cat_slice(ci), layout.cat_slice(ck)] = out
-            C[layout.cat_slice(ck), layout.cat_slice(ci)] = out.T
-    return C, N
+        for c in layout.cat:
+            out = np.asarray(outputs[f"cm_cat_{c}"], dtype=np.float64)  # (D, 1+len(xs))
+            sl = layout.cat_slice(c)
+            cnt = out[:, 0]
+            C[sl, 0] = C[0, sl] = cnt
+            np.fill_diagonal(C[sl, sl], cnt)  # one-hot: Xc·Xc = diag(count)
+            for i, xi in enumerate(xs):
+                C[sl, xidx[i]] = out[:, 1 + i]
+                C[xidx[i], sl] = out[:, 1 + i]
+
+        for i, ci in enumerate(layout.cat):
+            for ck in layout.cat[i + 1:]:
+                out = np.asarray(outputs[f"cm_cat2_{ci}_{ck}"], dtype=np.float64)[..., 0]
+                C[layout.cat_slice(ci), layout.cat_slice(ck)] = out
+                C[layout.cat_slice(ck), layout.cat_slice(ci)] = out.T
+        return C, N
 
 
 def compute_covar(ds: Dataset, database: Optional[Database] = None,

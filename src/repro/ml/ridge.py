@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.ml.covar import CovarLayout
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass
@@ -49,45 +50,46 @@ def bgd(C: np.ndarray, N: float, layout: CovarLayout, lam: float = 1e-3,
     the convergence loop runs in float64 on host — the paper's point is that
     this step is *cheap* once the engine has produced the sufficient
     statistics."""
-    Cff, Cfl, Cll = _split(C, layout)
-    n_f = Cff.shape[0]
+    with span("ml.ridge.bgd", p=layout.p):
+        Cff, Cfl, Cll = _split(C, layout)
+        n_f = Cff.shape[0]
 
-    # Jacobi preconditioning: one-hot blocks make the covar badly
-    # conditioned; substituting θ = D·φ with D = diag(Cff/N + λ)^{-1/2}
-    # solves the *same* ridge problem in a well-scaled space
-    dscale = 1.0 / np.sqrt(np.maximum(np.diag(Cff) / N + lam, 1e-12))
-    Cff = Cff * dscale[:, None] * dscale[None, :]
-    Cfl = Cfl * dscale
-    d2 = dscale * dscale
+        # Jacobi preconditioning: one-hot blocks make the covar badly
+        # conditioned; substituting θ = D·φ with D = diag(Cff/N + λ)^{-1/2}
+        # solves the *same* ridge problem in a well-scaled space
+        dscale = 1.0 / np.sqrt(np.maximum(np.diag(Cff) / N + lam, 1e-12))
+        Cff = Cff * dscale[:, None] * dscale[None, :]
+        Cfl = Cfl * dscale
+        d2 = dscale * dscale
 
-    def obj(th):
-        return (th @ Cff @ th - 2 * th @ Cfl + Cll) / (2 * N) + \
-            0.5 * lam * (th * th) @ d2
+        def obj(th):
+            return (th @ Cff @ th - 2 * th @ Cfl + Cll) / (2 * N) + \
+                0.5 * lam * (th * th) @ d2
 
-    def grad(th):
-        return (Cff @ th - Cfl) / N + lam * d2 * th
+        def grad(th):
+            return (Cff @ th - Cfl) / N + lam * d2 * th
 
-    th = np.zeros(n_f)
-    g = grad(th)
-    prev_th, prev_g = th, g
-    alpha = 1e-6
-    it = 0
-    while it < max_iters and np.linalg.norm(g) > tol * max(1.0, np.linalg.norm(th)):
-        if it > 0:
-            dth, dg = th - prev_th, g - prev_g
-            denom = dth @ dg
-            alpha = abs((dth @ dth) / denom) if abs(denom) > 1e-300 else alpha
-            alpha = float(np.clip(alpha, 1e-12, 1e6))
-        j0, gg = obj(th), g @ g
-        while obj(th - alpha * g) > j0 - 0.5 * alpha * gg and alpha > 1e-16:
-            alpha *= 0.5
-        prev_th, prev_g = th, g
-        th = th - alpha * g
+        th = np.zeros(n_f)
         g = grad(th)
-        it += 1
-    final_obj = float(obj(th))
-    th = th * dscale          # back to the unscaled parameterization
-    return RidgeResult(theta=th, iterations=it, objective=final_obj)
+        prev_th, prev_g = th, g
+        alpha = 1e-6
+        it = 0
+        while it < max_iters and np.linalg.norm(g) > tol * max(1.0, np.linalg.norm(th)):
+            if it > 0:
+                dth, dg = th - prev_th, g - prev_g
+                denom = dth @ dg
+                alpha = abs((dth @ dth) / denom) if abs(denom) > 1e-300 else alpha
+                alpha = float(np.clip(alpha, 1e-12, 1e6))
+            j0, gg = obj(th), g @ g
+            while obj(th - alpha * g) > j0 - 0.5 * alpha * gg and alpha > 1e-16:
+                alpha *= 0.5
+            prev_th, prev_g = th, g
+            th = th - alpha * g
+            g = grad(th)
+            it += 1
+        final_obj = float(obj(th))
+        th = th * dscale          # back to the unscaled parameterization
+        return RidgeResult(theta=th, iterations=it, objective=final_obj)
 
 
 def predict(theta: np.ndarray, layout: CovarLayout, rows: dict) -> np.ndarray:

@@ -2,8 +2,10 @@
 
 The runtime counterpart to the engine's hard contracts (DESIGN.md §11):
 
-* ``obs.trace``    — nestable timed spans + chrome://tracing export
-  (off by default; ``obs.enable_tracing()`` or ``REPRO_TRACE=1``);
+* ``obs.trace``    — nestable spans written into the JAX profiler's trace
+  as ``repro.<name>`` host annotations, beside the device's operations
+  (a no-op unless a profiler session is active: capture with
+  ``jax.profiler.trace(dir, create_perfetto_trace=True)``);
 * ``obs.metrics``  — process-local counters / gauges / fixed-bucket
   histograms (p50/p95/p99 without stored samples);
 * ``obs.workload`` — bounded recorder of every run/read call's query
@@ -18,18 +20,13 @@ zero-transfer / zero-retrace contracts hold with everything enabled.
 from repro.obs.log import StructuredLogger, get_logger
 from repro.obs.metrics import (Counter, Gauge, Histogram, Registry,
                                LATENCY_BUCKETS_US)
-from repro.obs.trace import (Tracer, enabled as tracing_enabled,
-                             export_chrome, get_tracer, span)
-from repro.obs.trace import enable as enable_tracing
-from repro.obs.trace import disable as disable_tracing
-from repro.obs.trace import clear as clear_trace
+from repro.obs.trace import span
 from repro.obs.workload import (QuerySignature, WorkloadRecord,
                                 WorkloadRecorder, agg_renders, routable,
                                 signature_of)
 
 __all__ = [
-    "span", "enable_tracing", "disable_tracing", "tracing_enabled",
-    "get_tracer", "export_chrome", "clear_trace", "Tracer",
+    "span",
     "Counter", "Gauge", "Histogram", "Registry", "LATENCY_BUCKETS_US",
     "QuerySignature", "WorkloadRecord", "WorkloadRecorder", "signature_of",
     "agg_renders", "routable",

@@ -1,14 +1,25 @@
-"""Engine-wide telemetry (DESIGN.md §11): tracing spans, metrics, workload
-recording, structured logging — and the headline design constraint that
+"""Engine-wide telemetry (DESIGN.md §11): program spans in the profiler's
+trace, named scopes in the compiled program, metrics, workload recording,
+structured logging — and the headline design constraint that
 instrumentation must NOT break the steady-state contracts: the sharded tick
 stays zero-transfer / zero-retrace and the epoch-pinning serving semantics
-hold with tracing + metrics + workload recording all enabled."""
+hold with a profiler session, metrics and workload recording all on.
 
+Spans are read back the way an operator reads them: captured with
+``jax.profiler.trace`` and read from the trace's host plane with
+``jax.profiler.ProfileData``."""
+
+import contextlib
+import glob
+import gzip
+import inspect
 import json
 import logging
+import re
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -16,8 +27,26 @@ import repro
 from repro import obs
 from repro.core import COUNT, Delta, Var, agg, query, schema, sum_of
 from repro.data import DeltaBatchUpdate, from_numpy
+from repro.obs import trace
 from repro.obs.metrics import Counter, Gauge, Histogram, Registry
 from repro.obs.workload import WorkloadRecorder, signature_of
+
+
+def read_spans(logdir):
+    """The program's spans in the newest trace under ``logdir``, read from
+    its host plane: ``(name, start_ns, end_ns, {stat: value})``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = max(glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                      "*.xplane.pb")), key=os.path.getmtime)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro.")]
 
 
 def make_schema():
@@ -48,14 +77,20 @@ def r2_rows(rng, k):
             "u": rng.normal(size=k).astype(np.float32)}
 
 
+@contextlib.contextmanager
+def profiled(logdir, **options):
+    """A profiler session; the list it yields holds the session's program
+    spans (``read_spans``) once the block has ended."""
+    spans = []
+    with jax.profiler.trace(str(logdir), **options):
+        yield spans
+    spans += read_spans(str(logdir))
+
+
 @pytest.fixture
-def tracing():
-    """Tracing enabled for the test, state restored after."""
-    obs.clear_trace()
-    obs.enable_tracing()
-    yield obs.get_tracer()
-    obs.disable_tracing()
-    obs.clear_trace()
+def tracing(tmp_path):
+    """A profiler session around the test body's block."""
+    return lambda: profiled(tmp_path / "trace")
 
 
 # ------------------------------------------------------------------- metrics
@@ -120,42 +155,47 @@ def test_metrics_are_thread_safe():
 # -------------------------------------------------------------------- tracing
 
 def test_span_noop_when_disabled():
-    obs.disable_tracing()
-    obs.clear_trace()
+    """With no profiler session a span is the shared null object: no
+    allocation, and nothing reaches the next session's trace."""
+    import repro.core  # noqa: F401  (installs the profiler's annotation)
+
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert obs.span("never.recorded", x=1) is trace._NULL
+    assert obs.span("never.recorded") is obs.span("other")
     with obs.span("never.recorded", x=1):
         pass
-    assert obs.get_tracer().events() == []
 
 
-def test_spans_nest_and_export_chrome(tracing, tmp_path):
-    with obs.span("outer", step=1):
-        with obs.span("inner"):
-            time.sleep(0.001)
-    evs = tracing.events()
-    names = {e["name"] for e in evs}
-    assert names == {"outer", "inner"}
-    outer = next(e for e in evs if e["name"] == "outer")
-    inner = next(e for e in evs if e["name"] == "inner")
-    assert outer["ph"] == "X" and outer["args"] == {"step": 1}
-    # nesting is reconstructed by time containment: inner ⊆ outer
-    assert outer["ts"] <= inner["ts"]
-    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1
-    assert inner["dur"] >= 1000                # slept >= 1ms, in us
+def test_spans_nest_and_export_chrome(tmp_path):
+    """Inside a session spans are ``repro.``-named host events carrying
+    their args as stats, nested by time containment; the profiler writes
+    them beside everything else, also as a Perfetto trace."""
+    with profiled(tmp_path, create_perfetto_trace=True) as spans:
+        with obs.span("outer", step=1, rel="R2"):
+            with obs.span("inner"):
+                time.sleep(0.001)
+    assert sorted(n for n, *_ in spans) == ["repro.inner", "repro.outer"]
+    outer = next(s for s in spans if s[0] == "repro.outer")
+    inner = next(s for s in spans if s[0] == "repro.inner")
+    assert outer[3] == {"step": 1, "rel": "R2"} and inner[3] == {}
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    assert inner[2] - inner[1] >= 1_000_000    # slept >= 1ms, in ns
 
-    path = tmp_path / "trace.json"
-    obs.export_chrome(str(path))
-    blob = json.loads(path.read_text())
-    assert len(blob["traceEvents"]) == 2
-    assert blob["displayTimeUnit"] == "ms"
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "perfetto_trace.json.gz"))
+    with gzip.open(path, "rt") as f:
+        text = f.read()
+    assert "repro.outer" in text and "repro.inner" in text
 
 
-def test_tracer_bounds_memory():
-    t = obs.Tracer(max_events=4)
-    for i in range(10):
-        t._record(f"e{i}", 0.0, 1e-6, {})
-    assert len(t.events()) == 4 and t.n_dropped == 6
-    t.clear()
-    assert t.events() == [] and t.n_dropped == 0
+def test_span_prefix_is_added_once(tmp_path):
+    """Only ``span`` adds the prefix: the program's names stay as written
+    (``ivm.tick``) and read back as ``repro.ivm.tick``."""
+    with profiled(tmp_path) as spans:
+        with obs.span("ivm.tick", rel="R2"):
+            pass
+    assert [(n, a) for n, _, _, a in spans] == [("repro.ivm.tick",
+                                                 {"rel": "R2"})]
 
 
 # ------------------------------------------------------------------- workload
@@ -257,30 +297,63 @@ def test_structured_logger_rate_limits(caplog):
 
 # ------------------------------------------------- wiring: compile/IVM/serve
 
-def test_spans_thread_through_engine(tracing, tmp_path):
+def test_spans_thread_through_engine(tracing):
     """One session exercising compile -> init -> tick -> serve leaves the
-    full span taxonomy in the trace, and the chrome export is loadable."""
-    db = repro.connect(make_schema(), tables=make_tables(),
-                       config=repro.ExecutionConfig(block_size=8))
-    v = db.views(QUERIES)
-    v.run()
-    live = db.views(QUERIES, maintain=True)
-    live.run()
-    rng = np.random.default_rng(3)
-    live.apply(DeltaBatchUpdate().insert("R2", r2_rows(rng, 3)))
-    srv = live.serve(max_pinned_epochs=4)
-    srv.read("q_count")
+    full span taxonomy in the profiler's trace."""
+    with tracing() as spans:
+        db = repro.connect(make_schema(), tables=make_tables(),
+                           config=repro.ExecutionConfig(block_size=8))
+        v = db.views(QUERIES)
+        v.run()
+        live = db.views(QUERIES, maintain=True)
+        live.run()
+        rng = np.random.default_rng(3)
+        live.apply(DeltaBatchUpdate().insert("R2", r2_rows(rng, 3)))
+        srv = live.serve(max_pinned_epochs=4)
+        srv.read("q_count")
 
-    names = {e["name"] for e in tracing.events()}
-    assert {"compile", "compile.roots", "compile.pushdown", "compile.group",
-            "compile.ir", "compile.schedule", "compile.bind",
-            "ivm.init", "ivm.apply", "ivm.validate", "ivm.tick",
-            "ivm.publish", "serve.read"} <= names
-    tick = next(e for e in tracing.events() if e["name"] == "ivm.tick")
-    assert tick["args"]["rel"] == "R2"
-    path = tmp_path / "trace.json"
-    obs.export_chrome(str(path))
-    assert json.loads(path.read_text())["traceEvents"]
+    names = {n for n, *_ in spans}
+    assert {"repro." + n for n in (
+        "compile", "compile.roots", "compile.pushdown", "compile.group",
+        "compile.ir", "compile.schedule", "compile.bind",
+        "ivm.init", "ivm.apply", "ivm.validate", "ivm.tick",
+        "ivm.publish", "serve.read")} <= names
+    tick = next(s for s in spans if s[0] == "repro.ivm.tick")
+    assert tick[3]["rel"] == "R2"
+
+
+def test_application_spans(tracing):
+    """The application layer's host work is spanned: the covar assembly
+    and the ridge solver, with the model's width as ``p``."""
+    from repro.data import datasets as D
+    from repro.ml.covar import compute_covar
+    from repro.ml.ridge import bgd
+
+    ds = D.make("favorita", scale=0.005)
+    with tracing() as spans:
+        C, N, layout, _ = compute_covar(ds)
+        bgd(C, N, layout, max_iters=5)
+    names = [n for n, *_ in spans]
+    assert "repro.ml.covar.assemble" in names
+    (fit,) = [s for s in spans if s[0] == "repro.ml.ridge.bgd"]
+    assert fit[3] == {"p": layout.p}
+
+
+def test_scan_scopes_name_device_ops():
+    """The compiled covar batch carries the plan's parts in its ops' op
+    names: the relation each scan step reads, and the block body's
+    gather, payload, partial sums and accumulation."""
+    from repro.data import datasets as D
+    from repro.ml.covar import covar_queries
+
+    ds = D.make("favorita", scale=0.005)
+    qs, _ = covar_queries(ds)
+    text = repro.connect(ds).views(qs).lower().compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    scopes = {p for o in op_names for p in o.split("/")}
+    assert "scan.Sales" in scopes and "outputs" in scopes
+    assert {"gather", "payload", "partials", "accumulate"} <= scopes
+    assert any(re.search(r"/scan\.Sales/.*/partials/", o) for o in op_names)
 
 
 def test_autotune_span_and_delta_provenance(tmp_path, tracing):
@@ -289,18 +362,19 @@ def test_autotune_span_and_delta_provenance(tmp_path, tracing):
     the delta lane no longer shadows the init full scan's."""
     cfg = repro.ExecutionConfig(
         block_size="auto", autotune_cache=str(tmp_path / "cache.json"))
-    db = repro.connect(make_schema(), tables=make_tables(), config=cfg)
-    live = db.views(QUERIES, maintain=True)
-    live.run()
-    rng = np.random.default_rng(3)
-    live.apply(DeltaBatchUpdate().insert("R2", r2_rows(rng, 3)))
+    with tracing() as spans:
+        db = repro.connect(make_schema(), tables=make_tables(), config=cfg)
+        live = db.views(QUERIES, maintain=True)
+        live.run()
+        rng = np.random.default_rng(3)
+        live.apply(DeltaBatchUpdate().insert("R2", r2_rows(rng, 3)))
     rep = live.explain()
     assert rep.autotune and rep.autotune_delta
     s = rep.summary()
     assert "autotune[batch]:" in s and "autotune[delta]:" in s
-    names = {e["name"] for e in tracing.events()}
-    assert "compile.autotune" in names and "autotune.tune" in names
-    assert "autotune.probe" in names
+    names = {n for n, *_ in spans}
+    assert {"repro.compile.autotune", "repro.autotune.tune",
+            "repro.autotune.probe"} <= names
 
 
 def test_server_stats_latency_lag_and_warning():
@@ -380,10 +454,12 @@ def test_execution_config_validates_telemetry_knobs():
 
 def test_sharded_steady_state_contract_with_telemetry(subproc):
     """Headline constraint: the sharded steady-state tick keeps the
-    zero-transfer / zero-retrace contract with tracing, metrics, and the
-    workload recorder ALL enabled — identical contract counters to the
+    zero-transfer / zero-retrace contract with a profiler session, metrics,
+    and the workload recorder ALL on — identical contract counters to the
     telemetry-off run in test_ivm_sharded.py."""
-    subproc("""
+    subproc(inspect.getsource(read_spans) + """
+import tempfile
+
 import numpy as np
 import jax
 
@@ -409,7 +485,8 @@ QUERIES = [
     query("q_delta", ["x4"], [agg(Var("u"), Delta("x1", "==", 1))]),
 ]
 
-obs.enable_tracing()                 # telemetry ON for the whole run
+logdir = tempfile.mkdtemp()
+jax.profiler.start_trace(logdir)     # telemetry ON for the whole run
 mesh = jax.make_mesh((len(jax.devices()),), ("data",))
 sharded = repro.connect(from_numpy(S, tables),
                         config=repro.ExecutionConfig(block_size=8, mesh=mesh))
@@ -442,22 +519,22 @@ assert len(mb._runners) == runners == 1
 st = srv.stats()
 assert st["tick_us"]["count"] >= 8 and st["tick_us"]["p50"] > 0
 assert st["read_us"]["count"] >= 6
-names = {e["name"] for e in obs.get_tracer().events()}
-assert {"ivm.apply", "ivm.tick", "ivm.publish", "serve.read"} <= names
+jax.profiler.stop_trace()
+names = {n for n, *_ in read_spans(logdir)}
+assert {"repro.ivm.apply", "repro.ivm.tick", "repro.ivm.publish",
+        "repro.serve.read"} <= names
 assert sharded.workload.n_recorded > 0
 print("OK")
 """, 4)
 
 
 @pytest.mark.slow
-def test_serving_epoch_consistent_under_updates_with_telemetry():
+def test_serving_epoch_consistent_under_updates_with_telemetry(tracing):
     """The concurrent-updater serving semantics (mirrors
-    test_serve_views.py) hold with tracing + metrics + workload recording
-    enabled: a pinned reader's epoch stays frozen while the writer
+    test_serve_views.py) hold with a profiler session, metrics and workload
+    recording on: a pinned reader's epoch stays frozen while the writer
     publishes, and the contract counters match the telemetry-off run."""
-    obs.clear_trace()
-    obs.enable_tracing()
-    try:
+    with tracing() as spans:
         db = repro.connect(make_schema(), tables=make_tables(),
                            config=repro.ExecutionConfig(block_size=8))
         live = db.views(QUERIES, maintain=True)
@@ -492,18 +569,17 @@ def test_serving_epoch_consistent_under_updates_with_telemetry():
         assert st["n_updates"] == len(updates)
         assert st["n_rejected_updates"] == 0
         assert st["tick_us"]["count"] == len(updates)
-        names = {e["name"] for e in obs.get_tracer().events()}
-        assert {"ivm.apply", "serve.read"} <= names
-    finally:
-        obs.disable_tracing()
-        obs.clear_trace()
+    names = {n for n, *_ in spans}
+    assert {"repro.ivm.apply", "repro.serve.read"} <= names
 
 
 @pytest.mark.slow
-def test_telemetry_overhead_under_5_percent():
-    """The no-sync instrumentation rule, quantified: steady-state tick wall
-    with tracing+metrics enabled stays within 5% of disabled (interleaved
-    min-of-N pairs — min is robust to scheduler noise in both directions)."""
+def test_telemetry_overhead_under_5_percent(tmp_path):
+    """The no-sync instrumentation rule, quantified: inside a profiler
+    session, the steady-state tick wall with the engine's spans written
+    stays within 5% of the wall with them muted (interleaved min-of-N pairs
+    — min is robust to scheduler noise in both directions).  The session's
+    own events (JAX's dispatch, XLA's) cost the same on both sides."""
     db = repro.connect(make_schema(), tables=make_tables(),
                        config=repro.ExecutionConfig(block_size=8))
     live = db.views(QUERIES, maintain=True)
@@ -515,25 +591,27 @@ def test_telemetry_overhead_under_5_percent():
         return (DeltaBatchUpdate().insert("R2", r2_rows(rng, 4))
                 .delete("R2", rng.choice(20, 2, replace=False)))
 
-    import jax
-
     def tick():
         jax.block_until_ready(mb.apply(fixed_update())["q_count"])
 
     for _ in range(5):                          # warm pad buckets + runners
         tick()
     t_off, t_on = [], []
-    for _ in range(40):                         # interleaved A/B pairs
-        obs.disable_tracing()
-        t0 = time.perf_counter()
-        tick()
-        t_off.append(time.perf_counter() - t0)
-        obs.enable_tracing()
-        t0 = time.perf_counter()
-        tick()
-        t_on.append(time.perf_counter() - t0)
-    obs.disable_tracing()
-    obs.clear_trace()
+    live = trace._is_enabled
+    with jax.profiler.trace(str(tmp_path)):
+        assert live()
+        try:
+            for _ in range(40):                 # interleaved A/B pairs
+                trace._is_enabled = trace._never
+                t0 = time.perf_counter()
+                tick()
+                t_off.append(time.perf_counter() - t0)
+                trace._is_enabled = live
+                t0 = time.perf_counter()
+                tick()
+                t_on.append(time.perf_counter() - t0)
+        finally:
+            trace._is_enabled = live
     assert min(t_on) <= min(t_off) * 1.05 + 200e-6, (
         f"telemetry overhead: on={min(t_on) * 1e6:.0f}us "
         f"off={min(t_off) * 1e6:.0f}us")

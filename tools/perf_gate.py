@@ -14,9 +14,9 @@ baseline file carries:
 * ``BENCH_serving.json``: the sustained-load serving stress
   (``benchmarks/bench_serving.py``).  Contract fields gate hard — zero
   rejected updates, zero reader-thread errors, eviction churn actually
-  exercised, one recorded workload signature per served view, a
-  non-degenerate latency distribution, and a non-empty trace export;
-  p50/p99 read latency and ticks/s gate loose.
+  exercised, one recorded workload signature per served view, and a
+  non-degenerate latency distribution; p50/p99 read latency and ticks/s
+  gate loose.
 * ``BENCH_routing.json``: ad-hoc query routing
   (``benchmarks/bench_routing.py``).  Contract fields gate hard — every
   tier allclose to a from-scratch compile (a routed answer that drifts is
@@ -109,9 +109,6 @@ def check(current: dict, baseline: dict, *, time_tol: float,
         yield ("serving/n_evictions", baseline.get("n_evictions"),
                current.get("n_evictions"), ">= 1",
                (current.get("n_evictions") or 0) >= 1)
-        yield ("serving/trace_events", baseline.get("trace_events"),
-               current.get("trace_events"), ">= 1",
-               (current.get("trace_events") or 0) >= 1)
         p50 = current.get("read_p50_us")
         p99 = current.get("read_p99_us")
         yield ("serving/read_count", baseline.get("read_count"),
