@@ -62,13 +62,17 @@ class BatchStats:
     #: static kernel-launch sites per full pass (pallas; 0 for xla) — the
     #: quantity launch fusion shrinks to one per scan step
     n_kernel_launches: int = 0
+    #: views the xla backend accumulates through a compact per-block
+    #: partial over the segments a block touches (``lowering/xla.py``)
+    n_compact_views: int = 0
 
     def summary(self) -> str:
         return (f"A={self.n_app_aggregates} I={self.n_intermediate_cols} "
                 f"V={self.n_views} (pre-merge {self.n_views_premerge}) "
                 f"G={self.n_groups} levels={self.group_levels} "
                 f"scans={self.n_scan_steps} (fused {self.n_fused_scans}) "
-                f"launches={self.n_kernel_launches}")
+                f"launches={self.n_kernel_launches} "
+                f"compact={self.n_compact_views}")
 
 
 def _jit_batch(run):
@@ -115,6 +119,7 @@ class CompiledBatch:
             n_fused_scans=sched.n_fused_groups,
             roots=self.roots,
             n_kernel_launches=self.plan.n_kernel_launches(),
+            n_compact_views=self.plan.n_compact_views(),
         )
 
     @property

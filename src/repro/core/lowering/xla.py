@@ -4,9 +4,13 @@ The paper-faithful default.  Rows stream through ``lax.scan`` in fixed-size
 blocks (HBM→VMEM tiles on real hardware); each block gathers incoming views
 once, evaluates every fused view's payload, and accumulates via
 ``jax.ops.segment_sum`` (local group-bys) or a plain axis-sum (scalar /
-pulled-only views).  Tracing the step program *is* LMFAO's code-generation
-layer (DESIGN.md §2): the emitted HLO is specialized to the schema, the
-fused view set, and the aggregate batch.
+pulled-only views).  A local group-by with many more segments than a block
+has rows sums each block into a compact partial over only the segments the
+block touches, then scatter-adds it into the accumulator
+(:func:`takes_compact`).
+Tracing the step program *is* LMFAO's code-generation layer (DESIGN.md §2):
+the emitted HLO is specialized to the schema, the fused view set, and the
+aggregate batch.
 """
 
 from __future__ import annotations
@@ -17,14 +21,35 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.aggregates import Params
-from repro.core.ir import StepProgram
+from repro.core.ir import SegmentSpec, StepProgram, ViewProgram
 from repro.core.lowering import common
+
+#: a view takes the compact path once its segments outnumber this many
+#: times a block's rows: below, the dense zero fill and add over every
+#: segment is cheaper than a sort (one-block timings on a v5e, PERF.md §6)
+COMPACT_SEGMENTS_PER_ROW = 8
+
+
+def takes_compact(vp: ViewProgram, block_size: int) -> bool:
+    """Whether ``vp``, scanned in blocks of the step's ``block_size`` rows,
+    accumulates each block through a compact partial over the block's
+    distinct segments, in place of a partial zero-filled over every
+    segment."""
+    return (vp.seg is not None
+            and vp.seg.n_segments > COMPACT_SEGMENTS_PER_ROW * block_size)
 
 
 class XlaBackend:
     """Lowers one scan step to a blocked ``lax.scan`` over the relation."""
 
     name = "xla"
+
+    @staticmethod
+    def count_compact(prog: StepProgram, config) -> int:
+        """Views of ``prog`` on the compact path at the config's block size
+        ("auto" counts at the default, as an unresolved step runs)."""
+        bs = _block_size(config)
+        return sum(takes_compact(vp, bs) for vp in prog.views)
 
     def run_step(self, prog: StepProgram, rel_cols: Mapping[str, jnp.ndarray],
                  arrays: Dict[int, jnp.ndarray], params: Params, *,
@@ -35,12 +60,10 @@ class XlaBackend:
         -1 delete, 0 padding).  ``None`` keeps the unweighted path.
         ``n_valid``/``offset`` may be Python ints or traced scalars (dynamic
         valid-row counts of capacity-padded resident relations)."""
-        from repro.core.autotune import DEFAULT_BLOCK_SIZE
-
-        block_size = (config.block_size if isinstance(config.block_size, int)
-                      else DEFAULT_BLOCK_SIZE)  # unresolved "auto" -> default
+        block_size = _block_size(config)
         cols_blocked, iota, B, n_pad = common.block_columns(
             rel_cols, weights, block_size)
+        compact = tuple(takes_compact(vp, block_size) for vp in prog.views)
 
         # batched views carry the param-batch (node) axis in front: one
         # relation pass accumulates all N parameter settings at once
@@ -60,26 +83,45 @@ class XlaBackend:
                 gathered = common.gather_children(prog.gathers, blk_cols,
                                                   arrays, B)
 
-            contribs = []
-            for vp in prog.views:
+            contribs, keys = [], {}
+            for vp, cp in zip(prog.views, compact):
                 with jax.named_scope("payload"):
                     payload = common.view_payload(vp, blk_cols, gathered,
                                                   params, valid, B, n_nodes)
                 with jax.named_scope("partials"):
-                    contribs.append(_partials(vp, payload, blk_cols))
-            # the block's partial sums are formed apart from the carried
-            # accumulators: XLA would otherwise fold ``acc + segment_sum``
-            # into one scatter-add onto ``acc``, adding rows one at a time
-            # to the running f32 total (a COUNT stalls at 2^24 on the TPU)
+                    if cp:
+                        if vp.seg not in keys:
+                            keys[vp.seg] = _block_segments(blk_cols, vp.seg)
+                        ids, slot = keys[vp.seg]
+                        contribs.append(
+                            (ids, _segment_sum(vp, payload, slot, B)))
+                    else:
+                        contribs.append(_partials(vp, payload, blk_cols))
+            # the block's partial sums (with the segment ids of compact
+            # ones) are formed apart from the carried accumulators: XLA would
+            # otherwise fold the block's segment_sum into the update of
+            # ``acc`` (the dense add or the compact scatter), adding rows one
+            # at a time to the running f32 total (a COUNT stalls at 2^24 on
+            # the TPU)
             contribs = jax.lax.optimization_barrier(tuple(contribs))
             with jax.named_scope("accumulate"):
-                return tuple(a + c for a, c in zip(accs, contribs)), None
+                return tuple(_accumulate(vp, a, c, cp) for vp, a, c, cp
+                             in zip(prog.views, accs, contribs,
+                                    compact)), None
 
         accs, _ = jax.lax.scan(body, accs, (cols_blocked, iota))
 
         with jax.named_scope("finalize"):
             for vp, acc in zip(prog.views, accs):
                 arrays[vp.vid] = common.finalize(vp, acc)
+
+
+def _block_size(config) -> int:
+    """The step's scan block: an unresolved "auto" runs at the default."""
+    from repro.core.autotune import DEFAULT_BLOCK_SIZE
+
+    return (config.block_size if isinstance(config.block_size, int)
+            else DEFAULT_BLOCK_SIZE)
 
 
 def _partials(vp, payload: jnp.ndarray, blk_cols) -> jnp.ndarray:
@@ -89,10 +131,41 @@ def _partials(vp, payload: jnp.ndarray, blk_cols) -> jnp.ndarray:
     if vp.seg is None:
         return payload.sum(axis=1 if vp.batched else 0)
     seg = common.segment_ids(blk_cols, vp.seg)
+    return _segment_sum(vp, payload, seg, vp.seg.n_segments)
+
+
+def _segment_sum(vp, payload: jnp.ndarray, seg: jnp.ndarray,
+                 n: int) -> jnp.ndarray:
+    """``segment_sum`` over the row axis, which follows the node axis of a
+    batched view."""
     if vp.batched:
         # segment_sum reduces axis 0: rows forward, node axis back, then
         # restore the leading node axis
         return jnp.swapaxes(jax.ops.segment_sum(
-            jnp.swapaxes(payload, 0, 1), seg,
-            num_segments=vp.seg.n_segments), 0, 1)
-    return jax.ops.segment_sum(payload, seg, num_segments=vp.seg.n_segments)
+            jnp.swapaxes(payload, 0, 1), seg, num_segments=n), 0, 1)
+    return jax.ops.segment_sum(payload, seg, num_segments=n)
+
+
+def _block_segments(blk_cols, spec: SegmentSpec):
+    """The block's distinct segment ids in its ``B`` slots, and each row's
+    slot.  Spare slots, and the slot of rows whose id lies outside the
+    segments, hold the id one past the last segment, which the scatter
+    drops as ``segment_sum`` drops such rows."""
+    n = spec.n_segments
+    seg = common.segment_ids(blk_cols, spec)
+    seg = jnp.where((seg >= 0) & (seg < n), seg, n)
+    return jnp.unique(seg, size=seg.shape[0], fill_value=n,
+                      return_inverse=True)
+
+
+def _accumulate(vp, acc: jnp.ndarray, contrib, compact: bool) -> jnp.ndarray:
+    """Add a block's contribution into the carried accumulator: the dense
+    partial whole, or the compact one scattered onto its segments (each
+    segment takes one add; spare slots fall outside and are dropped).  The
+    scatter is not told its ids are sorted or unique: on a v5e that hint
+    made it about eight times slower at 4,096 × 435 (PERF.md §6)."""
+    if not compact:
+        return acc + contrib
+    ids, partial = contrib
+    idx = (slice(None), ids) if vp.batched else ids
+    return acc.at[idx].add(partial, mode="drop")

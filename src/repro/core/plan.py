@@ -259,6 +259,14 @@ class ExecutablePlan:
             return 0
         return sum(count(prog, self.config) for prog in self.step_programs)
 
+    def n_compact_views(self) -> int:
+        """Views lowered on the xla backend's compact accumulate path,
+        summed over steps; 0 for other backends."""
+        count = getattr(self.backend, "count_compact", None)
+        if count is None:
+            return 0
+        return sum(count(prog, self.config) for prog in self.step_programs)
+
     # ------------------------------------------------------------------ api
 
     def bind(self, n_rows: Dict[str, int], n_nodes: Optional[int] = None):

@@ -240,3 +240,104 @@ def test_autotuned_blocking_smoke(tmp_path):
     for k in out:
         np.testing.assert_allclose(out[k], np.asarray(ref_out[k], np.float64),
                                    rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# xla accumulate paths: a view with more segments than a block has rows sums
+# each block into a compact partial over the segments it touches and
+# scatter-adds it; the dense path zero-fills a partial over every segment.
+# Each segment gets the same f32 adds in the same order, so the two agree
+# bit for bit.
+
+_BLOCK = 64
+_A, _B = 40, 30                      # 1,200 (a, b) segments
+
+
+def _compact_case(case):
+    """(columns, n_valid, weights, batched, n_nodes) of one case."""
+    rng = np.random.default_rng(14)
+    n = 4 * _BLOCK
+    a, b = rng.integers(0, _A, n), rng.integers(0, _B, n)
+    n_valid, weights = n, None
+    if case == "one_segment":
+        a, b = np.full(n, 7), np.full(n, 11)
+    elif case == "distinct_segments":
+        code = rng.permutation(_A * _B)[:n]
+        a, b = code // _B, code % _B
+    elif case == "padded_last_block":
+        n = 3 * _BLOCK + 21
+        a, b, n_valid = a[:n], b[:n], n - 9
+    elif case == "ivm_weights":
+        weights = rng.choice(np.float32([1.0, -1.0, 0.0]), n)
+    cols = {"a": a.astype(np.int32), "b": b.astype(np.int32),
+            "u": rng.normal(size=len(a)).astype(np.float32)}
+    return cols, n_valid, weights, case == "batched", 3
+
+
+@pytest.mark.parametrize("case", ["unbatched", "batched", "ivm_weights",
+                                  "padded_last_block", "one_segment",
+                                  "distinct_segments"])
+def test_compact_accumulate_matches_dense(case, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    import repro
+    from repro.core.aggregates import Param
+    from repro.core.lowering import xla
+
+    cols, n_valid, weights, batched, n_nodes = _compact_case(case)
+    S = schema([("a", "categorical", _A), ("b", "categorical", _B),
+                ("u", "continuous", 0)], [("R", ["a", "b", "u"])])
+    aggs = [COUNT, sum_of("u"), agg(Pow("u", 2))]
+    params = {}
+    if batched:
+        aggs.append(agg(Var("u"), Delta("b", "==",
+                                        Param("t", batched=True))))
+        params = {"t": jnp.arange(n_nodes, dtype=jnp.float32)}
+    qs = [query("q_ab", ["a", "b"], aggs), query("q_a", ["a"], [COUNT])]
+    db = from_numpy(S, {"R": cols})
+    plan = repro.connect(db, config=repro.ExecutionConfig(
+        block_size=_BLOCK)).views(qs).compiled.plan
+    (prog,) = plan.step_programs
+    segs = [vp.seg.n_segments for vp in prog.views if vp.seg is not None]
+    # q_ab's view passes the threshold, q_a's does not
+    bound = xla.COMPACT_SEGMENTS_PER_ROW * _BLOCK
+    assert max(segs) > bound and min(segs) <= bound
+
+    def run(segments_per_row):
+        monkeypatch.setattr(xla, "COMPACT_SEGMENTS_PER_ROW",
+                            segments_per_row)
+
+        def step(cols, n_valid, weights):
+            arrays = {}
+            xla.XlaBackend().run_step(
+                prog, cols, arrays, params, n_valid=n_valid, offset=0,
+                config=plan.config, n_nodes=n_nodes if batched else None,
+                weights=weights)
+            return arrays
+
+        w = None if weights is None else jnp.asarray(weights)
+        # n_valid is traced: the dynamic valid-row count of resident
+        # relations
+        out = jax.jit(step)({k: jnp.asarray(v) for k, v in cols.items()},
+                            jnp.int32(n_valid), w)
+        return ({vid: np.asarray(v) for vid, v in out.items()},
+                xla.XlaBackend.count_compact(prog, plan.config))
+
+    compact, n_compact = run(xla.COMPACT_SEGMENTS_PER_ROW)
+    dense, n_dense = run(10 ** 9)
+    assert n_compact == 1 and n_dense == 0
+    assert compact.keys() == dense.keys()
+    for vid in dense:
+        np.testing.assert_array_equal(compact[vid], dense[vid],
+                                      err_msg=f"view {vid}")
+
+    # and both are the sums: COUNT per (a, b) against numpy
+    w = np.ones(len(cols["a"])) if weights is None else weights
+    w = np.where(np.arange(len(w)) < n_valid, w, 0.0)
+    expect = np.zeros((_A, _B))
+    np.add.at(expect, (cols["a"], cols["b"]), w)
+    (vp,) = [vp for vp in prog.views if vp.group_by == ("a", "b")]
+    got = dense[vp.vid][..., 0]
+    got = got[0] if batched else got
+    np.testing.assert_allclose(got, expect, rtol=0, atol=1e-6)

@@ -272,6 +272,33 @@ else:
         np.testing.assert_allclose(got, expect, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("shrink,n_compact", [(0, 0), (1, 1)])
+def test_compact_threshold_by_segments_per_block(shrink, n_compact):
+    """A view takes the xla backend's compact accumulate path only when its
+    segments outnumber ``COMPACT_SEGMENTS_PER_ROW`` times a block's rows:
+    64 (a, b) segments stay dense at exactly that many and go compact in
+    blocks one row shorter; both give the sums."""
+    from repro.core.lowering.xla import COMPACT_SEGMENTS_PER_ROW
+
+    block_size = 64 // COMPACT_SEGMENTS_PER_ROW - shrink
+    assert 64 == COMPACT_SEGMENTS_PER_ROW * (block_size + shrink)
+    S = schema([("a", "categorical", 8), ("b", "categorical", 8),
+                ("u", "continuous", 0)], [("R", ["a", "b", "u"])])
+    rng = np.random.default_rng(5)
+    T = {"R": {"a": rng.integers(0, 8, 300), "b": rng.integers(0, 8, 300),
+               "u": rng.normal(size=300).astype(np.float32)}}
+    q = query("q", ["a", "b"], [COUNT, sum_of("u")])
+    h = connect(from_numpy(S, T), config=ExecutionConfig(
+        block_size=block_size)).views([q])
+    assert h.stats.n_compact_views == n_compact
+    assert f"compact={n_compact}" in h.stats.summary()
+    got = np.asarray(h.run()["q"], dtype=np.float64)
+    expect = np.zeros((8, 8, 2))
+    np.add.at(expect, (T["R"]["a"], T["R"]["b"]),
+              np.stack([np.ones(300), T["R"]["u"]], axis=-1))
+    np.testing.assert_allclose(got, expect, rtol=1e-5, atol=1e-5)
+
+
 def test_rip_validation_rejects_bad_tree():
     S = schema([("a", "key", 2), ("b", "key", 2), ("c", "key", 2)],
                [("R1", ["a", "b"]), ("R2", ["b", "c"]), ("R3", ["a", "c"])])
