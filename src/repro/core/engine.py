@@ -65,6 +65,9 @@ class BatchStats:
     #: views the xla backend accumulates through a compact per-block
     #: partial over the segments a block touches (``lowering/xla.py``)
     n_compact_views: int = 0
+    #: accumulators the xla backend carries, summed over steps: one per
+    #: (segment key, batched) group of a step's views
+    n_accumulators: int = 0
 
     def summary(self) -> str:
         return (f"A={self.n_app_aggregates} I={self.n_intermediate_cols} "
@@ -72,7 +75,8 @@ class BatchStats:
                 f"G={self.n_groups} levels={self.group_levels} "
                 f"scans={self.n_scan_steps} (fused {self.n_fused_scans}) "
                 f"launches={self.n_kernel_launches} "
-                f"compact={self.n_compact_views}")
+                f"compact={self.n_compact_views} "
+                f"accumulators={self.n_accumulators}")
 
 
 def _jit_batch(run):
@@ -120,6 +124,7 @@ class CompiledBatch:
             roots=self.roots,
             n_kernel_launches=self.plan.n_kernel_launches(),
             n_compact_views=self.plan.n_compact_views(),
+            n_accumulators=self.plan.n_accumulators(),
         )
 
     @property

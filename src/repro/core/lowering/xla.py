@@ -4,7 +4,10 @@ The paper-faithful default.  Rows stream through ``lax.scan`` in fixed-size
 blocks (HBM→VMEM tiles on real hardware); each block gathers incoming views
 once, evaluates every fused view's payload, and accumulates via
 ``jax.ops.segment_sum`` (local group-bys) or a plain axis-sum (scalar /
-pulled-only views).  A local group-by with many more segments than a block
+pulled-only views).  The views of a step that share a segment key (and the
+node axis) share one accumulator, their payloads side by side along its last
+axis, so a block takes one partial and one accumulate per key
+(:func:`accumulator_groups`).  A key with many more segments than a block
 has rows sums each block into a compact partial over only the segments the
 block touches, then scatter-adds it into the accumulator
 (:func:`takes_compact`).
@@ -15,7 +18,10 @@ aggregate batch.
 
 from __future__ import annotations
 
-from typing import Dict, Mapping
+import dataclasses
+import itertools
+import math
+from typing import Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,19 +30,51 @@ from repro.core.aggregates import Params
 from repro.core.ir import SegmentSpec, StepProgram, ViewProgram
 from repro.core.lowering import common
 
-#: a view takes the compact path once its segments outnumber this many
+#: a key takes the compact path once its segments outnumber this many
 #: times a block's rows: below, the dense zero fill and add over every
 #: segment is cheaper than a sort (one-block timings on a v5e, PERF.md §6)
 COMPACT_SEGMENTS_PER_ROW = 8
 
 
-def takes_compact(vp: ViewProgram, block_size: int) -> bool:
-    """Whether ``vp``, scanned in blocks of the step's ``block_size`` rows,
-    accumulates each block through a compact partial over the block's
-    distinct segments, in place of a partial zero-filled over every
-    segment."""
-    return (vp.seg is not None
-            and vp.seg.n_segments > COMPACT_SEGMENTS_PER_ROW * block_size)
+def takes_compact(seg: Optional[SegmentSpec], block_size: int) -> bool:
+    """Whether an accumulator on segment key ``seg``, scanned in blocks of
+    the step's ``block_size`` rows, takes each block through a compact
+    partial over the block's distinct segments, in place of a partial
+    zero-filled over every segment."""
+    return (seg is not None
+            and seg.n_segments > COMPACT_SEGMENTS_PER_ROW * block_size)
+
+
+@dataclasses.dataclass(frozen=True)
+class AccumulatorGroup:
+    """Views of one step that accumulate alike: the same segment key (or
+    none) and node axis.  They share an accumulator ``((N,)?,
+    (n_segments,)?, width)``; each view's payload, flattened to
+    ``prod(pulled_dims) × n_aggs`` columns, takes ``offsets[i]`` onward."""
+
+    seg: Optional[SegmentSpec]
+    batched: bool
+    views: Tuple[ViewProgram, ...]
+    offsets: Tuple[int, ...]
+    width: int
+
+
+def _view_width(vp: ViewProgram) -> int:
+    return math.prod(vp.pulled_dims) * vp.n_aggs
+
+
+def accumulator_groups(prog: StepProgram) -> Tuple[AccumulatorGroup, ...]:
+    """The step's views grouped by (segment key, batched), in the order
+    each group's first view comes."""
+    members: Dict[tuple, list] = {}
+    for vp in prog.views:
+        members.setdefault((vp.seg, vp.batched), []).append(vp)
+    groups = []
+    for (seg, batched), views in members.items():
+        ends = tuple(itertools.accumulate(_view_width(vp) for vp in views))
+        groups.append(AccumulatorGroup(seg, batched, tuple(views),
+                                       (0,) + ends[:-1], ends[-1]))
+    return tuple(groups)
 
 
 class XlaBackend:
@@ -49,7 +87,13 @@ class XlaBackend:
         """Views of ``prog`` on the compact path at the config's block size
         ("auto" counts at the default, as an unresolved step runs)."""
         bs = _block_size(config)
-        return sum(takes_compact(vp, bs) for vp in prog.views)
+        return sum(takes_compact(vp.seg, bs) for vp in prog.views)
+
+    @staticmethod
+    def count_accumulators(prog: StepProgram, config) -> int:
+        """Accumulators ``prog`` carries: one per (segment key, batched)
+        group of its views."""
+        return len(accumulator_groups(prog))
 
     def run_step(self, prog: StepProgram, rel_cols: Mapping[str, jnp.ndarray],
                  arrays: Dict[int, jnp.ndarray], params: Params, *,
@@ -63,13 +107,15 @@ class XlaBackend:
         block_size = _block_size(config)
         cols_blocked, iota, B, n_pad = common.block_columns(
             rel_cols, weights, block_size)
-        compact = tuple(takes_compact(vp, block_size) for vp in prog.views)
+        groups = accumulator_groups(prog)
+        compact = tuple(takes_compact(g.seg, block_size) for g in groups)
 
         # batched views carry the param-batch (node) axis in front: one
         # relation pass accumulates all N parameter settings at once
-        accs = tuple(jnp.zeros(((n_nodes,) if vp.batched else ())
-                               + vp.acc_shape, dtype=jnp.float32)
-                     for vp in prog.views)
+        accs = tuple(jnp.zeros(_lead(g, n_nodes)
+                               + ((g.seg.n_segments,) if g.seg else ())
+                               + (g.width,), dtype=jnp.float32)
+                     for g in groups)
 
         def body(carry, xs):
             accs = carry
@@ -84,19 +130,22 @@ class XlaBackend:
                                                   arrays, B)
 
             contribs, keys = [], {}
-            for vp, cp in zip(prog.views, compact):
+            for g, cp in zip(groups, compact):
                 with jax.named_scope("payload"):
-                    payload = common.view_payload(vp, blk_cols, gathered,
-                                                  params, valid, B, n_nodes)
+                    payload = _group_payload(g, [common.view_payload(
+                        vp, blk_cols, gathered, params, valid, B, n_nodes)
+                        for vp in g.views])
                 with jax.named_scope("partials"):
                     if cp:
-                        if vp.seg not in keys:
-                            keys[vp.seg] = _block_segments(blk_cols, vp.seg)
-                        ids, slot = keys[vp.seg]
+                        # a batched and an unbatched group on one key share
+                        # the block's distinct ids
+                        if g.seg not in keys:
+                            keys[g.seg] = _block_segments(blk_cols, g.seg)
+                        ids, slot = keys[g.seg]
                         contribs.append(
-                            (ids, _segment_sum(vp, payload, slot, B)))
+                            (ids, _segment_sum(g, payload, slot, B)))
                     else:
-                        contribs.append(_partials(vp, payload, blk_cols))
+                        contribs.append(_partials(g, payload, blk_cols))
             # the block's partial sums (with the segment ids of compact
             # ones) are formed apart from the carried accumulators: XLA would
             # otherwise fold the block's segment_sum into the update of
@@ -105,15 +154,18 @@ class XlaBackend:
             # the TPU)
             contribs = jax.lax.optimization_barrier(tuple(contribs))
             with jax.named_scope("accumulate"):
-                return tuple(_accumulate(vp, a, c, cp) for vp, a, c, cp
-                             in zip(prog.views, accs, contribs,
+                return tuple(_accumulate(g, a, c, cp) for g, a, c, cp
+                             in zip(groups, accs, contribs,
                                     compact)), None
 
         accs, _ = jax.lax.scan(body, accs, (cols_blocked, iota))
 
         with jax.named_scope("finalize"):
-            for vp, acc in zip(prog.views, accs):
-                arrays[vp.vid] = common.finalize(vp, acc)
+            for g, acc in zip(groups, accs):
+                for vp, off in zip(g.views, g.offsets):
+                    view_acc = acc[..., off:off + _view_width(vp)]
+                    arrays[vp.vid] = common.finalize(vp, view_acc.reshape(
+                        _lead(g, n_nodes) + vp.acc_shape))
 
 
 def _block_size(config) -> int:
@@ -124,21 +176,40 @@ def _block_size(config) -> int:
             else DEFAULT_BLOCK_SIZE)
 
 
-def _partials(vp, payload: jnp.ndarray, blk_cols) -> jnp.ndarray:
-    """One block's contribution to a view: ``segment_sum`` over the view's
-    local group-by (a zero-filled partial per block), or the axis sum of a
-    scalar or pulled-only view."""
-    if vp.seg is None:
-        return payload.sum(axis=1 if vp.batched else 0)
-    seg = common.segment_ids(blk_cols, vp.seg)
-    return _segment_sum(vp, payload, seg, vp.seg.n_segments)
+def _lead(g: AccumulatorGroup, n_nodes) -> Tuple[int, ...]:
+    """The node axis of a batched group's arrays, else nothing."""
+    return (n_nodes,) if g.batched else ()
 
 
-def _segment_sum(vp, payload: jnp.ndarray, seg: jnp.ndarray,
-                 n: int) -> jnp.ndarray:
+def _group_payload(g: AccumulatorGroup, payloads) -> jnp.ndarray:
+    """The group's views' ``((N,)?, B, *pulled_dims, n_aggs)`` payloads side
+    by side as one ``((N,)?, B, width)``.  They are joined along a leading
+    column axis and moved behind the rows once: joined along the last axis,
+    the TPU's compiler copied each one-column piece into a lane-padded
+    layout, every block (on a v5e retailer's job took 29.5 s against 19.9,
+    PERF.md §6)."""
+    lead = 2 if g.batched else 1
+    return jnp.moveaxis(jnp.concatenate(
+        [jnp.moveaxis(p.reshape(p.shape[:lead] + (-1,)), -1, 0)
+         for p in payloads], axis=0), 0, -1)
+
+
+def _partials(g: AccumulatorGroup, payload: jnp.ndarray,
+              blk_cols) -> jnp.ndarray:
+    """One block's contribution to a group: ``segment_sum`` over the
+    group's key (a zero-filled partial per block), or the row sum of
+    scalar or pulled-only views."""
+    if g.seg is None:
+        return payload.sum(axis=-2)
+    seg = common.segment_ids(blk_cols, g.seg)
+    return _segment_sum(g, payload, seg, g.seg.n_segments)
+
+
+def _segment_sum(g: AccumulatorGroup, payload: jnp.ndarray,
+                 seg: jnp.ndarray, n: int) -> jnp.ndarray:
     """``segment_sum`` over the row axis, which follows the node axis of a
-    batched view."""
-    if vp.batched:
+    batched group."""
+    if g.batched:
         # segment_sum reduces axis 0: rows forward, node axis back, then
         # restore the leading node axis
         return jnp.swapaxes(jax.ops.segment_sum(
@@ -158,7 +229,8 @@ def _block_segments(blk_cols, spec: SegmentSpec):
                       return_inverse=True)
 
 
-def _accumulate(vp, acc: jnp.ndarray, contrib, compact: bool) -> jnp.ndarray:
+def _accumulate(g: AccumulatorGroup, acc: jnp.ndarray, contrib,
+                compact: bool) -> jnp.ndarray:
     """Add a block's contribution into the carried accumulator: the dense
     partial whole, or the compact one scattered onto its segments (each
     segment takes one add; spare slots fall outside and are dropped).  The
@@ -167,5 +239,5 @@ def _accumulate(vp, acc: jnp.ndarray, contrib, compact: bool) -> jnp.ndarray:
     if not compact:
         return acc + contrib
     ids, partial = contrib
-    idx = (slice(None), ids) if vp.batched else ids
+    idx = (slice(None), ids) if g.batched else ids
     return acc.at[idx].add(partial, mode="drop")

@@ -254,15 +254,21 @@ class ExecutablePlan:
         device kernels one scan block dispatches, summed over steps) — the
         quantity launch fusion shrinks.  0 for the xla backend (no custom
         kernels)."""
-        count = getattr(self.backend, "count_launches", None)
-        if count is None:
-            return 0
-        return sum(count(prog, self.config) for prog in self.step_programs)
+        return self._count("count_launches")
 
     def n_compact_views(self) -> int:
         """Views lowered on the xla backend's compact accumulate path,
         summed over steps; 0 for other backends."""
-        count = getattr(self.backend, "count_compact", None)
+        return self._count("count_compact")
+
+    def n_accumulators(self) -> int:
+        """Accumulators the xla backend carries (one per segment key and
+        node axis of a step's views), summed over steps; 0 for other
+        backends."""
+        return self._count("count_accumulators")
+
+    def _count(self, counter: str) -> int:
+        count = getattr(self.backend, counter, None)
         if count is None:
             return 0
         return sum(count(prog, self.config) for prog in self.step_programs)

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import COUNT, Engine, agg, query, schema, sum_of
-from repro.core.aggregates import Delta, Lambda, Pow, Var
+from repro.core.aggregates import Delta, Lambda, Param, Pow, Var
 from repro.data import datasets as D
 from repro.data import from_numpy
 
@@ -37,7 +37,7 @@ def _assert_equal(outs):
                                    rtol=1e-4, atol=1e-4, err_msg=k)
 
 
-def test_pallas_matches_xla_chain_batch():
+def _chain_batch():
     S = schema(
         [("x1", "categorical", 3), ("x2", "key", 4), ("x3", "key", 5),
          ("x4", "categorical", 3), ("u", "continuous", 0)],
@@ -53,14 +53,21 @@ def test_pallas_matches_xla_chain_batch():
         query("q_g", ["x1", "x4"], [COUNT, sum_of("u")]),
         query("q_delta", ["x4"], [agg(Var("u"), Delta("x1", "==", 1))]),
     ]
-    _assert_equal(_run_both((S, from_numpy(S, T)), queries, block_size=16))
+    return (S, from_numpy(S, T)), queries, dict(block_size=16)
 
 
-def test_pallas_matches_xla_ridge_batch():
+def _ridge_batch():
     from repro.ml.covar import covar_queries
     ds = D.make("retailer", scale=0.02)
     qs, _ = covar_queries(ds)
-    _assert_equal(_run_both(ds, qs))
+    return ds, qs, {}
+
+
+@pytest.mark.parametrize("make", [_chain_batch, _ridge_batch],
+                         ids=["chain", "ridge"])
+def test_pallas_matches_xla(make):
+    data, queries, compile_kw = make()
+    _assert_equal(_run_both(data, queries, **compile_kw))
 
 
 def test_pallas_matches_xla_tree_batch():
@@ -209,18 +216,14 @@ def test_block_rows_threads_through_config():
                                    rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("bad", [0, -8, 7, 129, "biggish"])
-def test_invalid_block_rows_rejected(bad):
+@pytest.mark.parametrize("field,bad", [
+    ("block_rows", 0), ("block_rows", -8), ("block_rows", 7),
+    ("block_rows", 129), ("block_rows", "biggish"),
+    ("block_size", 0), ("block_size", -1), ("block_size", "large")])
+def test_invalid_blocking_rejected(field, bad):
     import repro
-    with pytest.raises(ValueError, match="multiple of 8|block_rows"):
-        repro.ExecutionConfig(backend="pallas", block_rows=bad)
-
-
-@pytest.mark.parametrize("bad", [0, -1, "large"])
-def test_invalid_block_size_rejected(bad):
-    import repro
-    with pytest.raises(ValueError, match="block_size"):
-        repro.ExecutionConfig(block_size=bad)
+    with pytest.raises(ValueError, match=field):
+        repro.ExecutionConfig(backend="pallas", **{field: bad})
 
 
 def test_autotuned_blocking_smoke(tmp_path):
@@ -341,3 +344,181 @@ def test_compact_accumulate_matches_dense(case, monkeypatch):
     got = dense[vp.vid][..., 0]
     got = got[0] if batched else got
     np.testing.assert_allclose(got, expect, rtol=0, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# grouped accumulators: a step's views on one segment key (and node axis)
+# share one accumulator, one partial and one accumulate per block
+
+_NODES = np.int32([3, 7, 11])         # the batched param, one per node
+
+
+def _grouped_case():
+    """A two-relation batch rooted at the fact ``F`` whose ``F`` step mixes
+    every kind of group: two compact views on (a, b), one with a pulled
+    dimension; two dense views on (a); a pulled-only and a scalar view; a
+    batched and an unbatched compact group on (a, b, k)."""
+    import warnings
+
+    S = schema([("a", "categorical", 40), ("b", "categorical", 30),
+                ("k", "key", 6), ("c", "categorical", 5),
+                ("u", "continuous", 0), ("v", "continuous", 0)],
+               [("F", ["a", "b", "k", "u"]), ("D", ["k", "c", "v"])])
+    rng = np.random.default_rng(15)
+    n = 4 * _BLOCK + 23
+    T = {"F": {"a": rng.integers(0, 40, n), "b": rng.integers(0, 30, n),
+               "k": rng.integers(0, 6, n),
+               "u": rng.normal(size=n).astype(np.float32)},
+         "D": {"k": np.arange(6), "c": rng.integers(0, 5, 6),
+               "v": rng.normal(size=6).astype(np.float32)}}
+    t = Param("t", batched=True)
+    qs = [query("q_ab", ["a", "b"], [COUNT, sum_of("u")]),
+          query("q_abc", ["a", "b", "c"], [COUNT, agg(Var("u"), Var("v"))]),
+          query("q_a", ["a"], [COUNT, agg(Pow("u", 2))]),
+          query("q_ac", ["a", "c"], [sum_of("v")]),
+          query("q_c", ["c"], [sum_of("u")]),
+          query("q_all", [], [COUNT, sum_of("v")]),
+          query("q_abk", ["a", "b", "k"], [agg(Var("u"), Delta("b", "==", t))]),
+          query("q_abkc", ["a", "b", "k", "c"], [COUNT])]
+    db = from_numpy(S, T)
+    cols = {r: dict(rel.columns) for r, rel in db.relations.items()}
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        batch = Engine(S, sizes=db.sizes()).compile(
+            qs, block_size=_BLOCK, root_override={q.name: "F" for q in qs})
+    return S, T, db, cols, qs, batch
+
+
+def _oracle(S, T, q, weights):
+    """``q`` over the materialized join, each row weighted by its fact
+    row's weight; a leading node axis for the batched query."""
+    from repro.core.plan import materialize_join
+
+    tables = {r: dict(c) for r, c in T.items()}
+    tables["F"]["row"] = np.arange(len(T["F"]["a"]))
+    J = materialize_join(S, tables, order=["F", "D"])
+    w = weights[J["row"]]
+    batched = any(p.batched for a in q.aggregates for pr in a.products
+                  for term in pr.terms for p in term.params())
+    out = []
+    for t in (_NODES if batched else [None]):
+        cols = []
+        for a in q.aggregates:
+            val = np.zeros(len(w))
+            for prod in a.products:
+                v = np.ones(len(w))
+                for term in prod.terms:
+                    env = {at: J[at] for at in term.attrs()}
+                    v = v * np.asarray(term.evaluate(env, {"t": t}),
+                                       dtype=np.float64)
+                val += v
+            val = val * w
+            if q.group_by:
+                o = np.zeros([S.domain(g) for g in q.group_by])
+                np.add.at(o, tuple(J[g] for g in q.group_by), val)
+            else:
+                o = val.sum()
+            cols.append(o)
+        out.append(np.stack(cols, axis=-1))
+    return out[0] if len(out) == 1 else np.stack(out)
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["unweighted", "ivm_weights"])
+def test_grouped_accumulate_matches_oracle(weighted, monkeypatch):
+    """Every view of a step mixing compact, dense, scalar, pulled-only and
+    batched groups, several views on one key, gives the join's sums; with
+    IVM delta weights (±1, 0) on the fact's rows, the weighted sums."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.lowering import xla
+
+    S, T, db, cols, qs, batch = _grouped_case()
+    n = len(T["F"]["a"])
+    weights = (np.random.default_rng(16).choice(
+        np.float32([1.0, -1.0, 0.0]), n) if weighted
+        else np.ones(n, np.float32))
+    if weighted:
+        orig = xla.XlaBackend.run_step
+
+        def run_step(self, prog, rel_cols, *args, **kw):
+            if prog.rel == "F":
+                kw["weights"] = jnp.asarray(weights)
+            return orig(self, prog, rel_cols, *args, **kw)
+
+        monkeypatch.setattr(xla.XlaBackend, "run_step", run_step)
+
+    run = batch.plan.bind(db.sizes(), n_nodes=len(_NODES))
+    out = jax.jit(run)(cols, {"t": jnp.asarray(_NODES)})
+    for q in qs:
+        np.testing.assert_allclose(np.asarray(out[q.name], np.float64),
+                                   _oracle(S, T, q, weights),
+                                   rtol=1e-5, atol=1e-4, err_msg=q.name)
+
+
+def test_one_scatter_per_accumulator_group():
+    """The ``F`` step's lowered HLO scatters onto each segmented group's
+    ``(N?, n_segments, width)`` shape once, and onto no single view's:
+    the dense groups' ``segment_sum`` and the compact groups' scatter into
+    the accumulator.  ``n_accumulators`` counts the groups over steps;
+    ``n_compact_views`` still counts views."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.lowering import xla
+
+    _, _, db, cols, _, batch = _grouped_case()
+    plan = batch.plan
+    (prog,) = [p for p in plan.step_programs if p.rel == "F"]
+    groups = xla.accumulator_groups(prog)
+    shapes = [((g.seg is not None, g.batched,
+                tuple(len(vp.pulled) > 0 for vp in g.views)))
+              for g in groups]
+    assert sorted(shapes) == sorted([
+        (True, False, (False, True)),          # (a, b): two views
+        (True, False, (False, True)),          # (a): two views
+        (False, False, (True, False)),         # pulled-only and scalar
+        (True, True, (False,)),                # (a, b, k), batched
+        (True, False, (True,))])               # (a, b, k, c)
+    compact = {g.seg.attrs: xla.takes_compact(g.seg, _BLOCK)
+               for g in groups if g.seg is not None}
+    assert compact == {("a", "b"): True, ("a",): False,
+                       ("a", "b", "k"): True}
+
+    N = len(_NODES)
+
+    def step(cols, params):
+        arrays = {}
+        for p in plan.step_programs:
+            xla.XlaBackend().run_step(
+                p, cols[p.rel], arrays, params, n_valid=db.sizes()[p.rel],
+                offset=0, config=plan.config, n_nodes=N)
+        return arrays
+
+    hlo = jax.jit(step).lower(cols, {
+        "t": jnp.asarray(_NODES)}).compiler_ir("hlo").as_hlo_text()
+    scattered = [tuple(int(d) for d in m.split(","))
+                 for m in re.findall(r"= f32\[([\d,]+)\]\S* scatter\(", hlo)]
+    for g in groups:
+        if g.seg is None:
+            continue
+        lead = (N,) if g.batched else ()
+        assert scattered.count(lead + (g.seg.n_segments, g.width)) == 1
+        if len(g.views) > 1:
+            for vp in g.views:
+                w = xla._view_width(vp)
+                assert lead + (g.seg.n_segments, w) not in scattered
+    segs = {g.seg.n_segments for g in groups if g.seg is not None}
+    onto_keys = [s for s in scattered if s[0] in segs
+                 or (len(s) == 3 and s[0] == N and s[1] in segs)]
+    assert len(onto_keys) == sum(g.seg is not None for g in groups)
+
+    stats = batch.stats
+    assert stats.n_accumulators == sum(
+        len(xla.accumulator_groups(p)) for p in plan.step_programs)
+    assert stats.n_accumulators == 2 + len(groups)   # D: (k) and (k, c)
+    assert stats.n_compact_views == 4
+    assert f"accumulators={stats.n_accumulators}" in stats.summary()

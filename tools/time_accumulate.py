@@ -1,19 +1,23 @@
 #!/usr/bin/env python3
-"""Time one scan block's accumulate on the device, dense against compact,
-at the shape of one segmented view: the choice the ``xla`` backend makes
-by ``lowering/xla.py:COMPACT_SEGMENTS_PER_ROW``.
+"""Time one scan block's accumulate on the device: dense against compact,
+at the shape of one segmented view (the choice the ``xla`` backend makes by
+``lowering/xla.py:COMPACT_SEGMENTS_PER_ROW``), and one compact accumulator
+shared by several views on one key against one per view.
 
     python3 tools/time_accumulate.py --view 1158960x435 --view 90936x337 \
-        --out accumulate.json
+        --group 90936x337,2,2,33 --out accumulate.json
 
 Each case scans ``--blocks`` blocks of ``--block`` rows with the backend's
-own block functions: ``dense`` forms the partial over every segment and
-adds the whole accumulator; ``compact`` forms it over the block's distinct
-segments and scatter-adds them.  Segment ids are drawn uniformly over the
-view's segments (``uniform``, as the benchmark's fact rows are) or from a
-run of ``--block`` // 16 neighbouring segments (``clustered``).  A case's
-time is the fastest of ``--repeats`` timed scans over its blocks, after a
-warm-up; both paths sum the same floats, which the script checks.
+own block functions.  ``--view``: ``dense`` forms the partial over every
+segment and adds the whole accumulator; ``compact`` forms it over the
+block's distinct segments and scatter-adds them.  ``--group``: on the
+compact path, ``grouped`` forms one partial of the views' summed width and
+scatters it once; ``views`` forms and scatters one per view, the block's
+distinct segments shared.  Segment ids are drawn uniformly over the key's
+segments (``uniform``, as the benchmark's fact rows are) or from a run of
+``--block`` // 16 neighbouring segments (``clustered``).  A case's time is
+the fastest of ``--repeats`` timed scans over its blocks, after a warm-up;
+both sides sum the same floats, which the script checks.
 """
 
 from __future__ import annotations
@@ -35,25 +39,53 @@ from repro.core.ir import SegmentSpec  # noqa: E402
 from repro.core.lowering import xla  # noqa: E402
 
 
+def _key(n_segments: int):
+    spec = SegmentSpec(("k",), (n_segments,), n_segments)
+    return spec, SimpleNamespace(seg=spec, batched=False)
+
+
 def scan_fn(n_segments: int, compact: bool):
     """A jitted scan of blocks ``(seg, payload)`` into a donated
     ``(n_segments, width)`` accumulator through one path."""
-    spec = SegmentSpec(("k",), (n_segments,), n_segments)
-    vp = SimpleNamespace(seg=spec, batched=False)
+    spec, g = _key(n_segments)
 
     def body(acc, xs):
         seg, payload = xs
         cols = {"k": seg}
         if compact:
             ids, slot = xla._block_segments(cols, spec)
-            contrib = (ids, xla._segment_sum(vp, payload, slot,
+            contrib = (ids, xla._segment_sum(g, payload, slot,
                                              seg.shape[0]))
         else:
-            contrib = xla._partials(vp, payload, cols)
+            contrib = xla._partials(g, payload, cols)
         contrib = jax.lax.optimization_barrier(contrib)
-        return xla._accumulate(vp, acc, contrib, compact), None
+        return xla._accumulate(g, acc, contrib, compact), None
 
     return jax.jit(lambda acc, seg, x: jax.lax.scan(body, acc, (seg, x))[0],
+                   donate_argnums=0)
+
+
+def group_scan_fn(n_segments: int, widths, grouped: bool):
+    """A jitted scan of blocks ``(seg, payload)``, the payload the views'
+    columns side by side, into donated compact accumulators on one key:
+    one ``(n_segments, sum(widths))`` when ``grouped``, else one
+    ``(n_segments, width)`` per view."""
+    spec, g = _key(n_segments)
+    cuts = [sum(widths[:i]) for i in range(len(widths) + 1)]
+
+    def body(accs, xs):
+        seg, payload = xs
+        ids, slot = xla._block_segments({"k": seg}, spec)
+        parts = ([payload] if grouped else
+                 [payload[:, a:b] for a, b in zip(cuts, cuts[1:])])
+        contribs = jax.lax.optimization_barrier(tuple(
+            (ids, xla._segment_sum(g, p, slot, seg.shape[0]))
+            for p in parts))
+        return tuple(xla._accumulate(g, a, c, True)
+                     for a, c in zip(accs, contribs)), None
+
+    return jax.jit(lambda accs, seg, x: jax.lax.scan(body, accs,
+                                                     (seg, x))[0],
                    donate_argnums=0)
 
 
@@ -70,52 +102,81 @@ def inputs(n_segments: int, width: int, blocks: int, block: int,
     return seg.astype(jnp.int32), x
 
 
-def time_case(n_segments, width, blocks, block, keys, compact, repeats,
-              seed):
-    seg, x = inputs(n_segments, width, blocks, block, keys, seed)
-    f = scan_fn(n_segments, compact)
-    zeros = lambda: jnp.zeros((n_segments, width), jnp.float32)  # noqa: E731
+def time_scan(f, zeros, seg, x, repeats):
+    """The fastest of ``repeats`` scans of ``f`` from fresh accumulators,
+    after a warm-up; returns it with the warm-up's sums."""
     out = f(zeros(), seg, x)
-    out.block_until_ready()
+    jax.block_until_ready(out)
     best = float("inf")
     for _ in range(repeats):
         acc = zeros()
-        acc.block_until_ready()
+        jax.block_until_ready(acc)
         t0 = time.perf_counter()
-        f(acc, seg, x).block_until_ready()
+        jax.block_until_ready(f(acc, seg, x))
         best = min(best, time.perf_counter() - t0)
     return [best, out]
 
 
+def time_case(n_segments, width, blocks, block, keys, compact, repeats,
+              seed):
+    seg, x = inputs(n_segments, width, blocks, block, keys, seed)
+    return time_scan(scan_fn(n_segments, compact),
+                     lambda: jnp.zeros((n_segments, width), jnp.float32),
+                     seg, x, repeats)
+
+
+def time_group(n_segments, widths, blocks, block, keys, grouped, repeats,
+               seed):
+    seg, x = inputs(n_segments, sum(widths), blocks, block, keys, seed)
+    shapes = [sum(widths)] if grouped else widths
+    best, out = time_scan(
+        group_scan_fn(n_segments, widths, grouped),
+        lambda: tuple(jnp.zeros((n_segments, w), jnp.float32)
+                      for w in shapes), seg, x, repeats)
+    return [best, jnp.concatenate(out, axis=-1)]
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--view", action="append", required=True,
+    ap.add_argument("--view", action="append", default=[],
                     help="<segments>x<width>, e.g. 1158960x435")
+    ap.add_argument("--group", action="append", default=[],
+                    help="<segments>x<width>,<width>,..., one width a "
+                         "view, e.g. 90936x337,2,2,33")
     ap.add_argument("--block", type=int, default=4096)
     ap.add_argument("--blocks", type=int, default=64)
     ap.add_argument("--repeats", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    device = jax.devices()[0].device_kind
     rows = []
+
+    def add(row, res, sides):
+        diff = float(jnp.abs(res[0][1] - res[1][1]).max())
+        row.update({"block": args.block, "blocks": args.blocks,
+                    "device": device, "max_abs_diff": diff})
+        for side, (t, _) in zip(sides, res):
+            row[f"{side}_ms_per_block"] = t / args.blocks * 1e3
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+
     for view in args.view:
         n_segments, width = (int(v) for v in view.split("x"))
         for keys in ("uniform", "clustered"):
-            res = {}
-            for compact in (False, True):
-                res[compact] = time_case(n_segments, width, args.blocks,
-                                         args.block, keys, compact,
-                                         args.repeats, args.seed)
-            diff = float(jnp.abs(res[False][1] - res[True][1]).max())
-            del res[False][1], res[True][1]
-            row = {"segments": n_segments, "width": width, "keys": keys,
-                   "block": args.block, "blocks": args.blocks,
-                   "device": jax.devices()[0].device_kind,
-                   "dense_ms_per_block": res[False][0] / args.blocks * 1e3,
-                   "compact_ms_per_block": res[True][0] / args.blocks * 1e3,
-                   "max_abs_diff": diff}
-            rows.append(row)
-            print(json.dumps(row), flush=True)
+            add({"segments": n_segments, "width": width, "keys": keys},
+                [time_case(n_segments, width, args.blocks, args.block, keys,
+                           compact, args.repeats, args.seed)
+                 for compact in (False, True)], ("dense", "compact"))
+    for group in args.group:
+        n_segments, widths = group.split("x")
+        n_segments = int(n_segments)
+        widths = [int(w) for w in widths.split(",")]
+        for keys in ("uniform", "clustered"):
+            add({"segments": n_segments, "widths": widths, "keys": keys},
+                [time_group(n_segments, widths, args.blocks, args.block,
+                            keys, grouped, args.repeats, args.seed)
+                 for grouped in (False, True)], ("views", "grouped"))
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
