@@ -68,6 +68,9 @@ class BatchStats:
     #: accumulators the xla backend carries, summed over steps: one per
     #: (segment key, batched) group of a step's views
     n_accumulators: int = 0
+    #: steps the xla backend sorts by a compact key before their scan, at
+    #: the relation sizes the batch was compiled for (``lowering/xla.py``)
+    n_clustered_steps: int = 0
 
     def summary(self) -> str:
         return (f"A={self.n_app_aggregates} I={self.n_intermediate_cols} "
@@ -76,7 +79,8 @@ class BatchStats:
                 f"scans={self.n_scan_steps} (fused {self.n_fused_scans}) "
                 f"launches={self.n_kernel_launches} "
                 f"compact={self.n_compact_views} "
-                f"accumulators={self.n_accumulators}")
+                f"accumulators={self.n_accumulators} "
+                f"clustered={self.n_clustered_steps}")
 
 
 def _jit_batch(run):
@@ -95,8 +99,11 @@ def _jit_batch(run):
 class CompiledBatch:
     def __init__(self, schema: DatabaseSchema, tree: JoinTree,
                  result: PushdownResult, groups: List[ViewGroup],
-                 config: PlanConfig, roots: Dict[str, str]):
+                 config: PlanConfig, roots: Dict[str, str],
+                 sizes: Optional[Mapping[str, int]] = None):
         self.schema = schema
+        #: relation row counts the batch was compiled for
+        self.sizes = dict(sizes or {})
         self.tree = tree
         self.result = result
         self.groups = groups
@@ -125,6 +132,7 @@ class CompiledBatch:
             n_kernel_launches=self.plan.n_kernel_launches(),
             n_compact_views=self.plan.n_compact_views(),
             n_accumulators=self.plan.n_accumulators(),
+            n_clustered_steps=self.plan.n_clustered_steps(self.sizes),
         )
 
     @property
@@ -322,7 +330,7 @@ class Engine:
             # CompiledBatch builds the ExecutablePlan, which emits the
             # compile.ir / compile.schedule child spans
             return CompiledBatch(self.schema, self.tree, result, groups, cfg,
-                                 roots)
+                                 roots, self.sizes)
 
     def compile_incremental(self, queries: Sequence[Query], *,
                             multi_root: bool = True, block_size=4096,

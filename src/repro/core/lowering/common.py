@@ -53,6 +53,15 @@ def block_columns(rel_cols: Cols, weights: Optional[jnp.ndarray],
     return cols_blocked, iota, B, n_pad
 
 
+def valid_limit(n_pad: int, n_valid, offset) -> jnp.ndarray:
+    """How many leading rows of a partition of ``n_pad`` rows lie inside the
+    global window ``[offset, offset+n_valid)``: rows at or past it are
+    padding (it may be negative: no row is valid)."""
+    return jnp.minimum(jnp.asarray(n_pad, jnp.int32),
+                       jnp.asarray(n_valid, jnp.int32)
+                       - jnp.asarray(offset, jnp.int32))
+
+
 def block_validity(blk_cols: Dict[str, jnp.ndarray], blk_i: jnp.ndarray,
                    B: int, n_pad: int, n_valid, offset):
     """Per-row validity of one scan block: inside both the local (possibly
@@ -64,10 +73,8 @@ def block_validity(blk_cols: Dict[str, jnp.ndarray], blk_i: jnp.ndarray,
     Pops the weight column; returns ``(blk_cols, valid)``."""
     w_blk = blk_cols.pop(ROW_WEIGHT, None)
     row_idx = blk_i * B + jnp.arange(B, dtype=jnp.int32)
-    limit = jnp.minimum(jnp.asarray(n_pad, jnp.int32),
-                        jnp.asarray(n_valid, jnp.int32)
-                        - jnp.asarray(offset, jnp.int32))
-    valid = (row_idx < limit).astype(jnp.float32)
+    valid = (row_idx < valid_limit(n_pad, n_valid, offset)).astype(
+        jnp.float32)
     if w_blk is not None:
         valid = valid * w_blk
     return blk_cols, valid

@@ -10,7 +10,10 @@ axis, so a block takes one partial and one accumulate per key
 (:func:`accumulator_groups`).  A key with many more segments than a block
 has rows sums each block into a compact partial over only the segments the
 block touches, then scatter-adds it into the accumulator
-(:func:`takes_compact`).
+(:func:`takes_compact`).  A step whose relation is long against its widest
+compact key sorts its rows by that key once, before the scan; each block
+then covers a short run of segments and is added into one contiguous window
+of the accumulator (:func:`cluster_key`).
 Tracing the step program *is* LMFAO's code-generation layer (DESIGN.md §2):
 the emitted HLO is specialized to the schema, the fused view set, and the
 aggregate batch.
@@ -35,6 +38,13 @@ from repro.core.lowering import common
 #: segment is cheaper than a sort (one-block timings on a v5e, PERF.md §6)
 COMPACT_SEGMENTS_PER_ROW = 8
 
+#: a step sorts its rows by its widest compact key once its relation holds
+#: this many rows a segment of that key: a block of sorted rows then spans
+#: about a quarter of a block's segments, so its window (a block's rows of
+#: segments) rarely misses (one-sort and one-block timings on a v5e,
+#: PERF.md §6)
+CLUSTER_ROWS_PER_SEGMENT = 4
+
 
 def takes_compact(seg: Optional[SegmentSpec], block_size: int) -> bool:
     """Whether an accumulator on segment key ``seg``, scanned in blocks of
@@ -43,6 +53,23 @@ def takes_compact(seg: Optional[SegmentSpec], block_size: int) -> bool:
     zero-filled over every segment."""
     return (seg is not None
             and seg.n_segments > COMPACT_SEGMENTS_PER_ROW * block_size)
+
+
+def cluster_key(groups, n_rows: int,
+                block_size: int) -> Optional[SegmentSpec]:
+    """The key a step of ``n_rows`` rows sorts them by before its scan: that
+    of its widest compact group, when the relation holds at least
+    ``CLUSTER_ROWS_PER_SEGMENT`` rows a segment of it; else ``None`` (no
+    compact group, or a block's expected span reaches its window).  A
+    compact key has more than ``COMPACT_SEGMENTS_PER_ROW`` blocks' rows of
+    segments, so a step that sorts has more than 32 blocks to amortise the
+    sort over."""
+    compact = [g for g in groups if takes_compact(g.seg, block_size)]
+    if not compact:
+        return None
+    seg = max(compact, key=lambda g: g.width).seg
+    return (seg if n_rows >= CLUSTER_ROWS_PER_SEGMENT * seg.n_segments
+            else None)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,6 +122,13 @@ class XlaBackend:
         group of its views."""
         return len(accumulator_groups(prog))
 
+    @staticmethod
+    def count_clustered(prog: StepProgram, config, n_rows: int) -> int:
+        """1 if ``prog``, scanning ``n_rows`` rows, sorts them by a compact
+        key before its scan, else 0."""
+        return int(cluster_key(accumulator_groups(prog), n_rows,
+                               _block_size(config)) is not None)
+
     def run_step(self, prog: StepProgram, rel_cols: Mapping[str, jnp.ndarray],
                  arrays: Dict[int, jnp.ndarray], params: Params, *,
                  n_valid, offset, config, n_nodes=None,
@@ -105,17 +139,29 @@ class XlaBackend:
         ``n_valid``/``offset`` may be Python ints or traced scalars (dynamic
         valid-row counts of capacity-padded resident relations)."""
         block_size = _block_size(config)
-        cols_blocked, iota, B, n_pad = common.block_columns(
-            rel_cols, weights, block_size)
         groups = accumulator_groups(prog)
         compact = tuple(takes_compact(g.seg, block_size) for g in groups)
+        key = cluster_key(groups, int(next(iter(rel_cols.values())).shape[0]),
+                          block_size)
+        if key is not None:
+            with jax.named_scope("cluster"):
+                rel_cols, weights, n_valid = _cluster(
+                    rel_cols, weights, key, n_valid, offset)
+            offset = 0
+        cols_blocked, iota, B, n_pad = common.block_columns(
+            rel_cols, weights, block_size)
+        # a sorted step adds each block of a compact group into a window of
+        # B segments from the block's least id; B spare segments keep a
+        # window at the accumulator's end in bounds
+        window = tuple(cp and key is not None for cp in compact)
 
         # batched views carry the param-batch (node) axis in front: one
         # relation pass accumulates all N parameter settings at once
         accs = tuple(jnp.zeros(_lead(g, n_nodes)
-                               + ((g.seg.n_segments,) if g.seg else ())
+                               + ((g.seg.n_segments + B * w,) if g.seg
+                                  else ())
                                + (g.width,), dtype=jnp.float32)
-                     for g in groups)
+                     for g, w in zip(groups, window))
 
         def body(carry, xs):
             accs = carry
@@ -129,18 +175,24 @@ class XlaBackend:
                 gathered = common.gather_children(prog.gathers, blk_cols,
                                                   arrays, B)
 
-            contribs, keys = [], {}
-            for g, cp in zip(groups, compact):
+            contribs, payloads, keys = [], [], {}
+            for g, cp, w in zip(groups, compact, window):
                 with jax.named_scope("payload"):
                     payload = _group_payload(g, [common.view_payload(
                         vp, blk_cols, gathered, params, valid, B, n_nodes)
                         for vp in g.views])
+                # a window group forms its partial in _window_accumulate
+                payloads.append(payload if w else None)
                 with jax.named_scope("partials"):
-                    if cp:
+                    if w:
+                        contribs.append(None)
+                    elif cp:
                         # a batched and an unbatched group on one key share
                         # the block's distinct ids
                         if g.seg not in keys:
-                            keys[g.seg] = _block_segments(blk_cols, g.seg)
+                            keys[g.seg] = _block_segments(
+                                common.segment_ids(blk_cols, g.seg),
+                                g.seg.n_segments)
                         ids, slot = keys[g.seg]
                         contribs.append(
                             (ids, _segment_sum(g, payload, slot, B)))
@@ -153,15 +205,23 @@ class XlaBackend:
             # at a time to the running f32 total (a COUNT stalls at 2^24 on
             # the TPU)
             contribs = jax.lax.optimization_barrier(tuple(contribs))
-            with jax.named_scope("accumulate"):
-                return tuple(_accumulate(g, a, c, cp) for g, a, c, cp
-                             in zip(groups, accs, contribs,
-                                    compact)), None
+            out = []
+            for g, a, c, cp, p in zip(groups, accs, contribs, compact,
+                                      payloads):
+                if p is not None:
+                    out.append(_window_accumulate(
+                        g, a, p, common.segment_ids(blk_cols, g.seg), valid))
+                else:
+                    with jax.named_scope("accumulate"):
+                        out.append(_accumulate(g, a, c, cp))
+            return tuple(out), None
 
         accs, _ = jax.lax.scan(body, accs, (cols_blocked, iota))
 
         with jax.named_scope("finalize"):
-            for g, acc in zip(groups, accs):
+            for g, acc, w in zip(groups, accs, window):
+                if w:
+                    acc = acc[..., :g.seg.n_segments, :]
                 for vp, off in zip(g.views, g.offsets):
                     view_acc = acc[..., off:off + _view_width(vp)]
                     arrays[vp.vid] = common.finalize(vp, view_acc.reshape(
@@ -217,16 +277,72 @@ def _segment_sum(g: AccumulatorGroup, payload: jnp.ndarray,
     return jax.ops.segment_sum(payload, seg, num_segments=n)
 
 
-def _block_segments(blk_cols, spec: SegmentSpec):
-    """The block's distinct segment ids in its ``B`` slots, and each row's
-    slot.  Spare slots, and the slot of rows whose id lies outside the
-    segments, hold the id one past the last segment, which the scatter
-    drops as ``segment_sum`` drops such rows."""
-    n = spec.n_segments
-    seg = common.segment_ids(blk_cols, spec)
+def _block_segments(seg: jnp.ndarray, n: int):
+    """The block's distinct segment ids ``seg`` in its ``B`` slots, and
+    each row's slot.  Spare slots, and the slot of rows whose id lies
+    outside the ``n`` segments, hold the id ``n``, past the last segment,
+    which the scatter drops as ``segment_sum`` drops such rows (or adds
+    into a window accumulator's spare segments, which are sliced off)."""
     seg = jnp.where((seg >= 0) & (seg < n), seg, n)
     return jnp.unique(seg, size=seg.shape[0], fill_value=n,
                       return_inverse=True)
+
+
+def _cluster(rel_cols, weights, seg: SegmentSpec, n_valid, offset):
+    """The relation's columns (and row weights) sorted by their segment id
+    on ``seg`` in one sort, and how many leading rows are valid.  Rows
+    outside the scan's ``[offset, offset+n_valid)`` sort last, after the
+    rows whose id lies outside the key's segments, so the valid rows stay
+    the leading ones and the scan takes them from offset 0."""
+    n_pad = int(next(iter(rel_cols.values())).shape[0])
+    n = seg.n_segments
+    limit = common.valid_limit(n_pad, n_valid, offset)
+    ids = common.segment_ids(rel_cols, seg)
+    key = jnp.where(jnp.arange(n_pad, dtype=jnp.int32) < limit,
+                    jnp.where((ids >= 0) & (ids < n), ids, n), n + 1)
+    names = tuple(rel_cols)
+    extra = (() if weights is None
+             else (jnp.asarray(weights, dtype=jnp.float32),))
+    out = jax.lax.sort((key,) + tuple(rel_cols[a] for a in names) + extra,
+                       num_keys=1, is_stable=False)
+    return (dict(zip(names, out[1:1 + len(names)])),
+            out[-1] if extra else None, limit)
+
+
+def _window_accumulate(g: AccumulatorGroup, acc: jnp.ndarray,
+                       payload: jnp.ndarray, seg: jnp.ndarray,
+                       valid: jnp.ndarray) -> jnp.ndarray:
+    """Add a block of a sorted step into its compact group's accumulator
+    through one window of ``W`` (the block's rows) segments from ``lo``,
+    the least id among its counted rows (``valid`` nonzero, id in range): a
+    partial over the window's segments, then one read-modify-write of
+    ``acc[lo:lo + W]``.  A block whose ids span ``W`` or more takes the
+    compact path's distinct segments and scatter instead, under
+    ``accumulate.fallback``."""
+    n, W = g.seg.n_segments, seg.shape[0]
+    counted = (valid != 0) & (seg >= 0) & (seg < n)
+    lo = jnp.min(jnp.where(counted, seg, n))
+    hi = jnp.max(jnp.where(counted, seg, 0))
+
+    def window(acc):
+        with jax.named_scope("partials"):
+            partial = _segment_sum(g, payload,
+                                   jnp.where(counted, seg - lo, W), W)
+        # kept apart from the add into ``acc``, as in ``run_step``
+        partial = jax.lax.optimization_barrier(partial)
+        with jax.named_scope("accumulate"):
+            at = (0, lo, 0) if g.batched else (lo, 0)
+            cur = jax.lax.dynamic_slice(acc, at, partial.shape)
+            return jax.lax.dynamic_update_slice(acc, cur + partial, at)
+
+    def fallback(acc):
+        with jax.named_scope("accumulate.fallback"):
+            ids, slot = _block_segments(seg, n)
+            partial = jax.lax.optimization_barrier(
+                _segment_sum(g, payload, slot, W))
+            return _accumulate(g, acc, (ids, partial), True)
+
+    return jax.lax.cond(hi - lo < W, window, fallback, acc)
 
 
 def _accumulate(g: AccumulatorGroup, acc: jnp.ndarray, contrib,

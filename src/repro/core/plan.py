@@ -267,6 +267,15 @@ class ExecutablePlan:
         backends."""
         return self._count("count_accumulators")
 
+    def n_clustered_steps(self, n_rows: Mapping[str, int]) -> int:
+        """Steps the xla backend sorts by a compact key before their scan,
+        at the relations' row counts ``n_rows``; 0 for other backends."""
+        count = getattr(self.backend, "count_clustered", None)
+        if count is None:
+            return 0
+        return sum(count(prog, self.config, n_rows.get(prog.rel, 0))
+                   for prog in self.step_programs)
+
     def _count(self, counter: str) -> int:
         count = getattr(self.backend, counter, None)
         if count is None:

@@ -347,6 +347,205 @@ def test_compact_accumulate_matches_dense(case, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# clustered steps: a relation long against its widest compact key is sorted
+# by that key once, and each block of a compact group is added into one
+# window of the block's rows of segments (or, when its ids span more,
+# through the compact scatter).  Sums run in another order than unsorted,
+# so the two agree to float tolerance.
+
+_ROWS = 80 * _BLOCK                  # 4.3 rows a (a, b) segment
+_C = 2                               # (a, b, c): a nested compact key
+
+
+def _clustered_case(case):
+    """(columns, n_valid, offset, weights) of one case."""
+    rng = np.random.default_rng(16)
+    n = _ROWS
+    a, b = rng.integers(0, _A, n), rng.integers(0, _B, n)
+    n_valid, offset, weights = n, 0, None
+    if case == "sorted":
+        code = np.sort(a * _B + b)
+        a, b = code // _B, code % _B
+    elif case == "zipf":
+        a, b = D.zipf_codes(rng, n, _A), D.zipf_codes(rng, n, _B)
+    elif case == "span_exceeds_window":
+        # two far runs of segments: the block where one ends and the other
+        # starts spans more than a block's rows of segments
+        a = np.where(rng.random(n) < 0.5, 0, _A - 1)
+    elif case == "window_at_end":
+        a, b = np.full(n, _A - 1), rng.integers(_B - 10, _B, n)
+    elif case == "out_of_domain":
+        a = np.where(rng.random(n) < 0.05, _A + 5, a)
+        a = np.where(rng.random(n) < 0.05, -1, a)
+    elif case == "offset_n_valid":
+        offset, n_valid = 1000, 1000 + n - 100
+    elif case == "ivm_weights":
+        weights = rng.choice(np.float32([1.0, -1.0, 0.0]), n)
+    cols = {"a": a.astype(np.int32), "b": b.astype(np.int32),
+            "c": rng.integers(0, _C, n).astype(np.int32),
+            "u": rng.normal(size=n).astype(np.float32)}
+    return cols, n_valid, offset, weights
+
+
+def _sorted_spans(cols):
+    """The id span of each block of the rows sorted by (a, b)."""
+    code = np.sort(cols["a"] * _B + cols["b"]).reshape(-1, _BLOCK)
+    return code.max(axis=1) - code.min(axis=1), code.min(axis=1)
+
+
+@pytest.mark.parametrize("case", [
+    "sorted", "shuffled", "zipf", "span_exceeds_window", "window_at_end",
+    "out_of_domain", "offset_n_valid", "ivm_weights", "batched",
+    "nested_keys"])
+def test_clustered_accumulate_matches_unclustered(case, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    import repro
+    from repro.core.aggregates import Param
+    from repro.core.lowering import xla
+
+    cols, n_valid, offset, weights = _clustered_case(case)
+    batched, n_nodes = case == "batched", 3
+    S = schema([("a", "categorical", _A), ("b", "categorical", _B),
+                ("c", "categorical", _C), ("u", "continuous", 0)],
+               [("R", ["a", "b", "c", "u"])])
+    aggs = [COUNT, sum_of("u"), agg(Pow("u", 2))]
+    params = {}
+    if batched:
+        aggs.append(agg(Var("u"), Delta("b", "==",
+                                        Param("t", batched=True))))
+        params = {"t": jnp.arange(n_nodes, dtype=jnp.float32)}
+    qs = [query("q_ab", ["a", "b"], aggs), query("q_a", ["a"], [COUNT])]
+    if case == "nested_keys":
+        qs.append(query("q_abc", ["a", "b", "c"], [COUNT, sum_of("u")]))
+    in_domain = dict(cols, a=np.clip(cols["a"], 0, _A - 1))
+    plan = repro.connect(from_numpy(S, {"R": in_domain}),
+                         config=repro.ExecutionConfig(block_size=_BLOCK)
+                         ).views(qs).compiled.plan
+    (prog,) = plan.step_programs
+    groups = xla.accumulator_groups(prog)
+    key = xla.cluster_key(groups, _ROWS, _BLOCK)
+    assert key is not None and key.attrs == ("a", "b")
+    compact = [g for g in groups if xla.takes_compact(g.seg, _BLOCK)]
+    assert [g.batched for g in compact] == [batched] * (
+        1 + (case == "nested_keys"))
+    if case == "span_exceeds_window":
+        assert (_sorted_spans(cols)[0] >= _BLOCK).any()
+    if case == "window_at_end":
+        assert _sorted_spans(cols)[1][-1] + _BLOCK > _A * _B
+
+    def run(rows_per_segment):
+        monkeypatch.setattr(xla, "CLUSTER_ROWS_PER_SEGMENT",
+                            rows_per_segment)
+
+        def step(cols, n_valid, offset, weights):
+            arrays = {}
+            xla.XlaBackend().run_step(
+                prog, cols, arrays, params, n_valid=n_valid, offset=offset,
+                config=plan.config, n_nodes=n_nodes if batched else None,
+                weights=weights)
+            return arrays
+
+        w = None if weights is None else jnp.asarray(weights)
+        # n_valid and offset are traced: a resident or sharded relation's
+        out = jax.jit(step)({k: jnp.asarray(v) for k, v in cols.items()},
+                            jnp.int32(n_valid), jnp.int32(offset), w)
+        return ({vid: np.asarray(v) for vid, v in out.items()},
+                xla.XlaBackend.count_clustered(prog, plan.config, _ROWS))
+
+    clustered, n_clustered = run(xla.CLUSTER_ROWS_PER_SEGMENT)
+    unclustered, n_unclustered = run(10 ** 9)
+    assert (n_clustered, n_unclustered) == (1, 0)
+    assert clustered.keys() == unclustered.keys()
+    for vid in unclustered:
+        np.testing.assert_allclose(clustered[vid], unclustered[vid],
+                                   rtol=1e-5, atol=1e-5,
+                                   err_msg=f"view {vid}")
+
+    # and both are the sums: COUNT per (a, b) against numpy
+    w = np.ones(_ROWS) if weights is None else weights
+    w = np.where(np.arange(_ROWS) < n_valid - offset, w, 0.0)
+    ok = (cols["a"] >= 0) & (cols["a"] < _A)
+    expect = np.zeros((_A, _B))
+    np.add.at(expect, (cols["a"][ok], cols["b"][ok]), w[ok])
+    (vp,) = [vp for vp in prog.views if vp.group_by == ("a", "b")]
+    got = clustered[vp.vid][..., 0]
+    got = got[0] if batched else got
+    np.testing.assert_allclose(got, expect, rtol=0, atol=1e-6)
+
+
+def _cell_batch(name):
+    """The benchmark cell ``name``'s covar batch, compiled to a plan at its
+    configuration's full size over shapes (no data), and the sizes."""
+    import os
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    import repro
+    from repro.core.schema import schema as make_schema
+    from repro.data.datasets import Dataset
+    from repro.data.relations import Database, Relation
+    from repro.ml.covar import covar_queries
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import run as bench_run
+
+    cfg = bench_run.Cell(name).cfg
+    S = make_schema([tuple(a) for a in cfg.spec["attributes"]],
+                    [(r, cfg.attrs[r]) for r in cfg.relations])
+    cols = {r: {a: jax.ShapeDtypeStruct(
+        (cfg.n_rows(r),), jnp.float32 if cfg.kinds[a] == "continuous"
+        else jnp.int32) for a in cfg.attrs[r]} for r in cfg.relations}
+    data = Database(S, {r: Relation(r, c) for r, c in cols.items()})
+    ds = Dataset(cfg.name, S, {}, [tuple(e) for e in cfg.edges],
+                 cfg.features_cont, cfg.features_cat, cfg.label, cfg.fact,
+                 _db=data)
+    qs, _ = covar_queries(ds)
+    return repro.connect(ds).views(qs).compiled, data.sizes(), cfg.fact
+
+
+@pytest.mark.parametrize("cell, key, short", [
+    ("favorita.ridge", ("date", "store"), ["Items", "Transactions"]),
+    ("retailer.ridge", ("date", "locn"), ["Items", "Weather"])])
+def test_cluster_rule_picks_the_fact_scan(cell, key, short):
+    """At the benchmark's full sizes the shape rule sorts exactly the first
+    fact scan, by its widest compact key; no other step (those on the
+    ``short`` relations have compact groups, but few rows against their
+    keys) and no delta scan of a 0.1% update to the fact."""
+    from repro.core.ivm import MaintainedBatch
+    from repro.core.lowering import xla
+    from repro.data.relations import next_pow2
+
+    batch, sizes, fact = _cell_batch(cell)
+    plan = batch.plan
+    assert batch.stats.n_clustered_steps == 1
+    assert "clustered=1" in batch.stats.summary()
+    sorted_steps = [p for p in plan.step_programs
+                    if xla.XlaBackend.count_clustered(p, plan.config,
+                                                      sizes[p.rel])]
+    assert [p.rel for p in sorted_steps] == [fact]
+    (prog,) = sorted_steps
+    assert prog is [p for p in plan.step_programs if p.rel == fact][0]
+    groups = xla.accumulator_groups(prog)
+    assert xla.cluster_key(groups, sizes[fact], 4096).attrs == key
+    assert sorted({p.rel for p in plan.step_programs if p is not prog
+                   and any(xla.takes_compact(g.seg, 4096)
+                           for g in xla.accumulator_groups(p))}) == short
+
+    delta = MaintainedBatch(batch).delta_program(fact)
+    n_delta = next_pow2(sizes[fact] // 1000)
+    scans = [st for st in delta.steps if st.scans_delta]
+    assert scans
+    assert not any(xla.XlaBackend.count_clustered(st.prog, plan.config,
+                                                  n_delta) for st in scans)
+
+
+# ---------------------------------------------------------------------------
 # grouped accumulators: a step's views on one segment key (and node axis)
 # share one accumulator, one partial and one accumulate per block
 
@@ -522,3 +721,6 @@ def test_one_scatter_per_accumulator_group():
     assert stats.n_accumulators == 2 + len(groups)   # D: (k) and (k, c)
     assert stats.n_compact_views == 4
     assert f"accumulators={stats.n_accumulators}" in stats.summary()
+    # compact, but 279 rows against 1,200 (a, b) segments: no sort
+    assert stats.n_clustered_steps == 0
+    assert "clustered=0" in stats.summary()

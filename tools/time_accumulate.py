@@ -1,23 +1,35 @@
 #!/usr/bin/env python3
 """Time one scan block's accumulate on the device: dense against compact,
 at the shape of one segmented view (the choice the ``xla`` backend makes by
-``lowering/xla.py:COMPACT_SEGMENTS_PER_ROW``), and one compact accumulator
-shared by several views on one key against one per view.
+``lowering/xla.py:COMPACT_SEGMENTS_PER_ROW``), one compact accumulator
+shared by several views on one key against one per view, a sorted step's
+window against compact, and the sort of a relation's rows by its key.
 
     python3 tools/time_accumulate.py --view 1158960x435 --view 90936x337 \
-        --group 90936x337,2,2,33 --out accumulate.json
+        --group 90936x337,2,2,33 --window 90936x374x1380 \
+        --sort-rows 125497040 --out accumulate.json
 
-Each case scans ``--blocks`` blocks of ``--block`` rows with the backend's
-own block functions.  ``--view``: ``dense`` forms the partial over every
-segment and adds the whole accumulator; ``compact`` forms it over the
-block's distinct segments and scatter-adds them.  ``--group``: on the
-compact path, ``grouped`` forms one partial of the views' summed width and
-scatters it once; ``views`` forms and scatters one per view, the block's
-distinct segments shared.  Segment ids are drawn uniformly over the key's
-segments (``uniform``, as the benchmark's fact rows are) or from a run of
-``--block`` // 16 neighbouring segments (``clustered``).  A case's time is
-the fastest of ``--repeats`` timed scans over its blocks, after a warm-up;
-both sides sum the same floats, which the script checks.
+Each case but ``--sort-rows`` scans ``--blocks`` blocks of ``--block`` rows
+with the backend's own block functions.  ``--view``: ``dense`` forms the
+partial over every segment and adds the whole accumulator; ``compact``
+forms it over the block's distinct segments and scatter-adds them.
+``--group``: on the compact path, ``grouped`` forms one partial of the
+views' summed width and scatters it once; ``views`` forms and scatters one
+per view, the block's distinct segments shared.  Segment ids are drawn
+uniformly over the key's segments (``uniform``, as the benchmark's fact
+rows are) or from a run of ``--block`` // 16 neighbouring segments
+(``clustered``).  ``--window <segments>x<width>x<rows a segment>``: blocks
+of a relation sorted by the key, each a sorted run of ``--block`` // rows
+neighbouring segments; ``window`` adds each block into one window of the
+accumulator (``_window_accumulate``), ``compact`` takes the compact path.
+A case's time is the fastest of ``--repeats`` timed scans over its blocks,
+after a warm-up; both sides sum the same floats, which the script checks.
+
+``--sort-rows N`` times the reordering of ``N`` rows of ``--sort-cols``
+columns by a key over ``--sort-segments`` segments on the device: ``sort``
+carries the columns through one ``lax.sort`` (what ``lowering/xla.py``'s
+``_cluster`` does), ``argsort`` sorts the key with the row index and takes
+each column by it; the fastest of ``--repeats`` after a warm-up.
 """
 
 from __future__ import annotations
@@ -44,20 +56,24 @@ def _key(n_segments: int):
     return spec, SimpleNamespace(seg=spec, batched=False)
 
 
-def scan_fn(n_segments: int, compact: bool):
+def scan_fn(n_segments: int, compact: bool, window: bool = False):
     """A jitted scan of blocks ``(seg, payload)`` into a donated
-    ``(n_segments, width)`` accumulator through one path."""
-    spec, g = _key(n_segments)
+    ``(n_segments, width)`` accumulator through one path; with ``window``,
+    into a ``(n_segments + block, width)`` one through windows."""
+    _, g = _key(n_segments)
 
     def body(acc, xs):
         seg, payload = xs
-        cols = {"k": seg}
+        if window:
+            valid = jnp.ones(seg.shape, jnp.float32)
+            return xla._window_accumulate(g, acc, payload, seg,
+                                          valid), None
         if compact:
-            ids, slot = xla._block_segments(cols, spec)
+            ids, slot = xla._block_segments(seg, n_segments)
             contrib = (ids, xla._segment_sum(g, payload, slot,
                                              seg.shape[0]))
         else:
-            contrib = xla._partials(g, payload, cols)
+            contrib = xla._partials(g, payload, {"k": seg})
         contrib = jax.lax.optimization_barrier(contrib)
         return xla._accumulate(g, acc, contrib, compact), None
 
@@ -70,12 +86,12 @@ def group_scan_fn(n_segments: int, widths, grouped: bool):
     columns side by side, into donated compact accumulators on one key:
     one ``(n_segments, sum(widths))`` when ``grouped``, else one
     ``(n_segments, width)`` per view."""
-    spec, g = _key(n_segments)
+    _, g = _key(n_segments)
     cuts = [sum(widths[:i]) for i in range(len(widths) + 1)]
 
     def body(accs, xs):
         seg, payload = xs
-        ids, slot = xla._block_segments({"k": seg}, spec)
+        ids, slot = xla._block_segments(seg, n_segments)
         parts = ([payload] if grouped else
                  [payload[:, a:b] for a, b in zip(cuts, cuts[1:])])
         contribs = jax.lax.optimization_barrier(tuple(
@@ -90,14 +106,18 @@ def group_scan_fn(n_segments: int, widths, grouped: bool):
 
 
 def inputs(n_segments: int, width: int, blocks: int, block: int,
-           keys: str, seed: int):
+           keys, seed: int):
+    """Blocks of segment ids and payloads; ``keys`` is ``"uniform"``,
+    ``"clustered"``, or the rows a segment of a sorted relation."""
     k1, k2, k3 = jax.random.split(jax.random.key(seed), 3)
     if keys == "uniform":
         seg = jax.random.randint(k1, (blocks, block), 0, n_segments)
     else:
-        run = max(block // 16, 1)
+        run = max(block // (16 if keys == "clustered" else keys), 1)
         start = jax.random.randint(k3, (blocks, 1), 0, n_segments - run)
         seg = start + jax.random.randint(k1, (blocks, block), 0, run)
+        if keys != "clustered":
+            seg = jnp.sort(seg, axis=1)
     x = jax.random.normal(k2, (blocks, block, width), jnp.float32)
     return seg.astype(jnp.int32), x
 
@@ -125,6 +145,58 @@ def time_case(n_segments, width, blocks, block, keys, compact, repeats,
                      seg, x, repeats)
 
 
+def time_window(n_segments, width, rows_per_segment, blocks, block,
+                window, repeats, seed):
+    seg, x = inputs(n_segments, width, blocks, block, rows_per_segment,
+                    seed)
+    rows = n_segments + block if window else n_segments
+    best, out = time_scan(scan_fn(n_segments, True, window),
+                          lambda: jnp.zeros((rows, width), jnp.float32),
+                          seg, x, repeats)
+    return [best, out[:n_segments]]
+
+
+def sort_fn(carry: bool):
+    """A jitted reorder of ``cols`` by ``key``: one sort carrying them, or
+    the key sorted with the row index and each column taken by it."""
+    if carry:
+        return jax.jit(lambda key, cols: jax.lax.sort(
+            (key,) + tuple(cols), num_keys=1, is_stable=False))
+
+    def argsort(key, cols):
+        key, perm = jax.lax.sort((key, jnp.arange(key.shape[0],
+                                                  dtype=jnp.int32)),
+                                 num_keys=1, is_stable=False)
+        return (key,) + tuple(jnp.take(c, perm) for c in cols)
+
+    return jax.jit(argsort)
+
+
+def time_sort(n_rows, n_cols, n_segments, repeats, seed):
+    """``{side: (seconds, (key sorted, column sums))}`` of reordering
+    ``n_rows`` rows of ``n_cols`` columns, int32 and float32 by turns, by a
+    key."""
+    ks = jax.random.split(jax.random.key(seed), n_cols + 1)
+    key = jax.random.randint(ks[0], (n_rows,), 0, n_segments)
+    cols = tuple(jax.random.randint(k, (n_rows,), 0, 1 << 20) if i % 2
+                 else jax.random.normal(k, (n_rows,), jnp.float32)
+                 for i, k in enumerate(ks[1:]))
+    out = {}
+    for side, carry in (("sort", True), ("argsort", False)):
+        f = sort_fn(carry)
+        res = jax.block_until_ready(f(key, cols))
+        check = (bool(jnp.all(res[0][1:] >= res[0][:-1])),
+                 [float(jnp.sum(c.astype(jnp.float32))) for c in res[1:]])
+        del res
+        best = float("inf")
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            jax.block_until_ready(f(key, cols))
+            best = min(best, time.perf_counter() - t0)
+        out[side] = (best, check)
+    return out
+
+
 def time_group(n_segments, widths, blocks, block, keys, grouped, repeats,
                seed):
     seg, x = inputs(n_segments, sum(widths), blocks, block, keys, seed)
@@ -143,6 +215,12 @@ def main(argv=None) -> int:
     ap.add_argument("--group", action="append", default=[],
                     help="<segments>x<width>,<width>,..., one width a "
                          "view, e.g. 90936x337,2,2,33")
+    ap.add_argument("--window", action="append", default=[],
+                    help="<segments>x<width>x<rows a segment>, e.g. "
+                         "90936x374x1380")
+    ap.add_argument("--sort-rows", type=int, action="append", default=[])
+    ap.add_argument("--sort-cols", type=int, default=5)
+    ap.add_argument("--sort-segments", type=int, default=90936)
     ap.add_argument("--block", type=int, default=4096)
     ap.add_argument("--blocks", type=int, default=64)
     ap.add_argument("--repeats", type=int, default=5)
@@ -177,6 +255,26 @@ def main(argv=None) -> int:
                 [time_group(n_segments, widths, args.blocks, args.block,
                             keys, grouped, args.repeats, args.seed)
                  for grouped in (False, True)], ("views", "grouped"))
+    for window in args.window:
+        n_segments, width, per = (int(v) for v in window.split("x"))
+        add({"segments": n_segments, "width": width,
+             "rows_per_segment": per},
+            [time_window(n_segments, width, per, args.blocks, args.block,
+                         w, args.repeats, args.seed)
+             for w in (False, True)], ("compact", "window"))
+    for n_rows in args.sort_rows:
+        res = time_sort(n_rows, args.sort_cols, args.sort_segments,
+                        args.repeats, args.seed)
+        row = {"sort_rows": n_rows, "cols": args.sort_cols,
+               "segments": args.sort_segments, "device": device,
+               "key_sorted": res["sort"][1][0] and res["argsort"][1][0],
+               "max_col_sum_diff": max(
+                   abs(a - b) / max(abs(a), 1.0) for a, b in
+                   zip(res["sort"][1][1], res["argsort"][1][1]))}
+        for side, (t, _) in res.items():
+            row[f"{side}_s"] = t
+        rows.append(row)
+        print(json.dumps(row), flush=True)
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
