@@ -4,7 +4,7 @@ and per end-to-end batch (ISSUE 6 / ROADMAP item 5).
 Per kernel (``seg_aggregate``, ``tree_hist``, the whole-step
 ``fused_scan_block``, ``covar_xtx``): analytic FLOP/byte counts over a fixed
 shape, warm median wall time, and utilization against the host platform's
-peaks from ``benchmarks.roofline.peaks()`` (auto-detected backend;
+peaks from ``benchmarks.common.peaks()`` (auto-detected backend;
 env/CLI-overridable — CPU CI reports against honest host ceilings, not TPU
 constants).  Every kernel is also checked against its jnp oracle, so the
 bench doubles as a correctness gate.
@@ -33,8 +33,7 @@ import time
 import numpy as np
 import jax.numpy as jnp
 
-from benchmarks.common import BENCH_SCALE, row, timeit
-from benchmarks.roofline import peaks
+from benchmarks.common import BENCH_SCALE, peaks, row, timeit
 from repro.kernels import ops, ref
 
 #: machine-readable results of the last ``main()`` run (benchmarks/run.py

@@ -7,11 +7,55 @@ import os
 import subprocess
 import sys
 import time
-from typing import Callable
+from typing import Any, Callable, Dict, Optional
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 BENCH_SCALE = float(os.environ.get("BENCH_SCALE", "0.1"))
+
+#: per-chip peak defaults by host platform.  TPU numbers are v5e-class (per
+#: brief); CPU numbers are honest host-class ceilings so CI utilization
+#: reports are meaningful instead of vanishing against TPU constants.
+PLATFORM_PEAKS = {
+    "tpu": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+    "gpu": {"flops": 312e12, "hbm_bw": 2039e9, "link_bw": 300e9},  # A100-class
+    "cpu": {"flops": 2e11,   "hbm_bw": 40e9,   "link_bw": 10e9},   # host-class
+}
+
+
+def detect_platform() -> str:
+    """The host accelerator platform (``jax.default_backend()``), "cpu" when
+    jax is unavailable.  Overridable via ``REPRO_PLATFORM``."""
+    env = os.environ.get("REPRO_PLATFORM")
+    if env:
+        return env
+    try:
+        import jax
+        return jax.default_backend()
+    except Exception:
+        return "cpu"
+
+
+def peaks(platform: Optional[str] = None, *,
+          flops: Optional[float] = None, hbm_bw: Optional[float] = None,
+          link_bw: Optional[float] = None) -> Dict[str, Any]:
+    """Per-chip peak FLOPs / HBM / link bandwidth for ``platform`` (default:
+    auto-detected host backend).  Precedence per value: explicit argument >
+    env (``REPRO_PEAK_FLOPS`` / ``REPRO_HBM_BW`` / ``REPRO_LINK_BW``) >
+    platform table."""
+    plat = platform or detect_platform()
+    base = PLATFORM_PEAKS.get(plat, PLATFORM_PEAKS["cpu"])
+
+    def pick(arg, env_key, table_val):
+        if arg is not None:
+            return float(arg)
+        env = os.environ.get(env_key)
+        return float(env) if env else float(table_val)
+
+    return {"platform": plat,
+            "flops": pick(flops, "REPRO_PEAK_FLOPS", base["flops"]),
+            "hbm_bw": pick(hbm_bw, "REPRO_HBM_BW", base["hbm_bw"]),
+            "link_bw": pick(link_bw, "REPRO_LINK_BW", base["link_bw"])}
 
 
 def timeit(fn: Callable, *, warmup: int = 1, iters: int = 3) -> float:

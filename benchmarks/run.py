@@ -57,15 +57,6 @@ def main() -> None:
             json.dump(payload, f, indent=1, sort_keys=True)
         print(f"# wrote {path}", file=sys.stderr)
 
-    # dry-run + roofline tables (read from reports/, written by
-    # repro.launch.dryrun --all and benchmarks.roofline)
-    try:
-        if os.path.isdir("reports/dryrun"):
-            from benchmarks import report_experiments
-            print()
-            report_experiments.main()
-    except Exception:
-        traceback.print_exc()
     if not ok:
         sys.exit(1)
 

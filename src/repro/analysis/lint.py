@@ -10,8 +10,8 @@ committed allowlist (``tools/lint_allowlist.json``):
   ``.item()``, and ``float()`` / ``np.asarray()`` / ``np.array()`` wrapping
   a fresh ``jax``/``jnp`` call result.  The no-sync rule (DESIGN.md §11) is
   what keeps a tick one async dispatch; the allowlist names the few modules
-  with *documented* sync points (ingest, checkpoint gather, train-loop
-  logging, autotune timing probes, snapshot export).
+  with *documented* sync points (ingest, checkpoint gather, autotune
+  timing probes, snapshot export).
 * ``obs-no-device`` — nothing under ``obs/`` may import ``jax``: telemetry
   must observe the engine without ever touching (and so never syncing)
   device values.

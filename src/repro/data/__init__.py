@@ -1,4 +1,5 @@
-"""Data substrate: columnar relations, synthetic schemas, LM token pipeline."""
+"""Data substrate: columnar relations, synthetic schemas, engine-backed
+feature statistics."""
 
 from repro.data.relations import (Database, DeltaBatchUpdate, Relation,
                                   RelationDelta, ResidentRelation,

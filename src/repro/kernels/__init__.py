@@ -3,7 +3,6 @@
 covar_xtx       masked blocked XtX (the covar-matrix batch on the MXU)
 seg_aggregate   multi-aggregate segment reduction (the MOO scan)
 tree_hist       fused decision-tree node histogram (RT-node workload)
-flash_attention blockwise online-softmax attention (LM-zoo prefill)
 
 Each kernel: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit wrappers,
 padding, interpret switch), ref.py (pure-jnp oracles).

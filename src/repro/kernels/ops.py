@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.covar_xtx import covar_xtx_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.fused_scan import ReduceSpec, fused_scan_block_pallas
 from repro.kernels.padding import pad_dim as _pad_dim
 from repro.kernels.padding import pad_rows as _pad_rows
@@ -23,7 +22,7 @@ from repro.kernels.seg_aggregate import seg_aggregate_pallas
 from repro.kernels.tree_hist import tree_hist_batched_pallas, tree_hist_pallas
 
 __all__ = ["covar_xtx", "seg_aggregate", "tree_hist", "tree_hist_batched",
-           "fused_scan_block", "flash_attention", "ReduceSpec"]
+           "fused_scan_block", "ReduceSpec"]
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret", "feature_align"))
@@ -89,22 +88,3 @@ def fused_scan_block(codes: jnp.ndarray, fpay: jnp.ndarray,
                                    block_rows=block_rows, interpret=interpret,
                                    double_buffer=double_buffer)
 
-
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k", "interpret"))
-def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    causal: bool = True, window: int = 0,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = False) -> jnp.ndarray:
-    """Blockwise attention; pads sequence dims to tile multiples.  Padded
-    query rows produce garbage sliced away below; padded key columns are
-    excluded inside the kernel via the ``kv_len`` mask."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    qp = _pad_dim(q, 2, block_q)
-    kp = _pad_dim(k, 2, block_k)
-    vp = _pad_dim(v, 2, block_k)
-    out = flash_attention_pallas(qp, kp, vp, causal=causal, window=window,
-                                 kv_len=sk, block_q=block_q, block_k=block_k,
-                                 interpret=interpret)
-    return out[:, :, :sq, :]

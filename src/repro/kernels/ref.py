@@ -48,24 +48,3 @@ def fused_scan_block_ref(codes: jnp.ndarray, fpay: jnp.ndarray, specs):
         outs.append(seg_aggregate_ref(code, pay, sp.n_segments))
     return tuple(outs)
 
-
-def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                  causal: bool = True, window: int = 0) -> jnp.ndarray:
-    """Dense reference attention with GQA, causal and sliding-window masks."""
-    b, h, sq, d = q.shape
-    _, hkv, sk, _ = k.shape
-    group = h // hkv
-    kr = jnp.repeat(k, group, axis=1)
-    vr = jnp.repeat(v, group, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, kr).astype(jnp.float32) / (d ** 0.5)
-    rows = jnp.arange(sq)[:, None]
-    cols = jnp.arange(sk)[None, :]
-    mask = jnp.ones((sq, sk), dtype=bool)
-    if causal:
-        mask = mask & (cols <= rows)
-    if window > 0:
-        mask = mask & (cols > rows - window)
-    s = jnp.where(mask, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    p = jnp.where(jnp.isnan(p), 0.0, p)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, vr).astype(q.dtype)

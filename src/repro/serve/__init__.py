@@ -10,9 +10,6 @@ signature router answering *arbitrary* group-by aggregates from the
 session's views (exact match / subsumption re-aggregation / verified
 compile-and-cache), driven by an adaptive planner over the signature
 lattice.  Reached through ``Database.query`` / ``ViewServer.query``.
-
-``engine.py`` — the LM decode loop retained from the model-serving seed
-(batched greedy decoding; used by ``examples/serve_lm.py``).
 """
 
 from repro.core.ivm import EpochEvictedError

@@ -258,3 +258,54 @@ assert tb["psums_per_run"] >= 1
 assert "psums/run" in vb.explain().summary()
 print("OK")
 """, 4)
+
+
+def test_engine_sharded_matches_local(subproc):
+    subproc("""
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import Engine, schema, query, COUNT, sum_of, agg, Pow
+from repro.data import from_numpy
+rng = np.random.default_rng(1)
+S = schema([("k","key",16),("c","categorical",5),("u","continuous",0)],
+           [("F",["k","u"]),("D",["k","c"])])
+n = 1003
+T = {"F": {"k": rng.integers(0,16,n), "u": rng.normal(size=n).astype(np.float32)},
+     "D": {"k": np.arange(16), "c": rng.integers(0,5,16)}}
+db = from_numpy(S, T)
+eng = Engine(S, sizes=db.sizes())
+batch = eng.compile([query("byc", ["c"], [COUNT, sum_of("u"), agg(Pow("u",2))])],
+                    block_size=64)
+local = batch(db)
+mesh = jax.make_mesh((8,), ("data",))
+shard = batch.run_sharded(db, mesh)
+for k in local:
+    assert np.allclose(local[k], shard[k], rtol=1e-4, atol=1e-4)
+print("OK")
+""", n_devices=8)
+
+
+def test_engine_sharded_matches_local_1xN_mesh(subproc):
+    """run_sharded on a (1, N) mesh (data axis second) must equal the local
+    __call__ — covers the fused-scan schedule under shard_map + psum."""
+    subproc("""
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import Engine, schema, query, COUNT, sum_of, agg, Pow
+from repro.data import from_numpy
+rng = np.random.default_rng(7)
+S = schema([("k","key",16),("c","categorical",5),("u","continuous",0)],
+           [("F",["k","u"]),("D",["k","c"])])
+n = 517
+T = {"F": {"k": rng.integers(0,16,n), "u": rng.normal(size=n).astype(np.float32)},
+     "D": {"k": np.arange(16), "c": rng.integers(0,5,16)}}
+db = from_numpy(S, T)
+eng = Engine(S, sizes=db.sizes())
+batch = eng.compile([query("byc", ["c"], [COUNT, sum_of("u"), agg(Pow("u",2))]),
+                     query("tot", [], [COUNT, sum_of("u")])],
+                    block_size=32)
+local = batch(db)
+mesh = jax.make_mesh((1, 4), ("model", "data"))
+shard = batch.run_sharded(db, mesh, axis="data")
+for k in local:
+    assert np.allclose(local[k], shard[k], rtol=1e-4, atol=1e-4), k
+print("OK")
+""", n_devices=4)

@@ -199,32 +199,3 @@ def test_fused_scan_block_dbuf_matches_grid_bitwise():
                              interpret=True, double_buffer=False)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-
-
-@pytest.mark.parametrize("b,h,hkv,s,d", [(1, 2, 1, 64, 8), (2, 4, 2, 100, 16),
-                                         (1, 4, 4, 96, 32)])
-@pytest.mark.parametrize("causal,window", [(True, 0), (True, 24), (False, 0)])
-def test_flash_attention(b, h, hkv, s, d, causal, window):
-    rng = np.random.default_rng(b * s)
-    q = rng.normal(size=(b, h, s, d)).astype(np.float32)
-    k = rng.normal(size=(b, hkv, s, d)).astype(np.float32)
-    v = rng.normal(size=(b, hkv, s, d)).astype(np.float32)
-    got = ops.flash_attention(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-                              causal=causal, window=window, block_q=32,
-                              block_k=32, interpret=True)
-    want = ref.attention_ref(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-                             causal=causal, window=window)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-3, atol=2e-3)
-
-
-def test_flash_attention_bf16():
-    rng = np.random.default_rng(7)
-    q = jnp.asarray(rng.normal(size=(1, 2, 64, 16)), jnp.bfloat16)
-    k = jnp.asarray(rng.normal(size=(1, 2, 64, 16)), jnp.bfloat16)
-    v = jnp.asarray(rng.normal(size=(1, 2, 64, 16)), jnp.bfloat16)
-    got = ops.flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
-                              interpret=True)
-    want = ref.attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), rtol=5e-2, atol=5e-2)

@@ -8,14 +8,7 @@ from repro.data import datasets as D
 from repro.ml import chowliu, cubes, ridge, trees
 from repro.ml.covar import compute_covar
 
-ORDERS = {
-    "favorita": ["Oil", "Transactions", "Stores", "Sales", "Holiday", "Items"],
-    "retailer": ["Census", "Location", "Weather", "Inventory", "Items"],
-    "yelp": ["User", "Review", "Business", "Category", "Attribute"],
-    "tpcds": ["customer_demographics", "customer", "household_demographics",
-              "customer_address", "store_sales", "date_dim", "time_dim", "item",
-              "store", "promotion"],
-}
+from routes_oracle import ORDERS
 
 
 @pytest.fixture(scope="module")
@@ -113,20 +106,8 @@ def test_cubes_engine_equals_rollup(fav):
     np.testing.assert_allclose(a[cubes.cube_name(dims)], fin, rtol=1e-4, atol=1e-2)
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("name", ["retailer", "yelp", "tpcds"])
-def test_covar_other_schemas(name):
-    ds = D.make(name, scale=0.03)
-    J = materialize_join(ds.schema, ds.tables, order=ORDERS[name])
-    C, N, layout, _ = compute_covar(ds)
-    Cref, n = _oracle_covar(J, layout)
-    assert n == N, (n, N)
-    scale = max(1.0, np.abs(Cref).max())
-    assert np.abs(C - Cref).max() / scale < 1e-5
-
-
 def test_engine_backed_dataset_statistics(fav):
-    """data/statistics.py: the LM framework's data-layer statistics run
+    """data/statistics.py: feature moments and key-load counts run
     through the LMFAO engine and match the materialized join."""
     from repro.data.statistics import expert_load_aggregate, feature_moments
     ds, J = fav

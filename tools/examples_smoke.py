@@ -9,9 +9,6 @@ calls, still routes through the deprecated ``Engine.compile`` /
 ``Engine.compile_incremental`` entry points instead of the facade.  (The
 dedicated warning category keeps the gate sharp: third-party
 DeprecationWarnings cannot trip it.)
-
-The LM-seed examples (``train_lm.py``, ``serve_lm.py``) are out of scope —
-they exercise the model-serving stack, not the aggregate engine.
 """
 
 import os
